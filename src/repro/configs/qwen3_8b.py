@@ -19,6 +19,24 @@ CONFIG = register(ModelConfig(
 ))
 
 
+def chip_share() -> ModelConfig:
+    """One TPU v5e chip's share of a qwen3-8b training job, at the
+    published widths (d_model, heads, head_dim, d_ff, qk-norm unchanged).
+
+    Changed keys, and the deployment each cut stands for:
+      * vocab_size 151936 -> 18992: the vocabulary split over 8 chips
+        (embedding and unembedding sharded 8 ways); this chip holds 1/8.
+      * num_layers 36 -> 2: the other 34 layers run as further pipeline
+        stages on other chips (18 stages of 2 layers).
+
+    Sized by compiling the donated train step for one v5e: fp32 master
+    weights plus Adam state at a 2 x 4096-token batch fit 16 GiB with
+    headroom; the full vocabulary alone would need 19.9 GB of state.
+    """
+    return CONFIG.replace(name="qwen3-8b-chip-share", num_layers=2,
+                          vocab_size=151_936 // 8)
+
+
 def reduced() -> ModelConfig:
     return CONFIG.replace(
         name="qwen3-8b-reduced", num_layers=2, d_model=64, num_heads=4,

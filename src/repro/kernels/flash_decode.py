@@ -20,6 +20,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BLOCK_K = 256
 NEG_INF = -1e30
+LANES = 128      # m/l scratch rows span one full vreg width
 
 
 def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
@@ -33,7 +34,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    cache_len = len_ref[0]
+    cache_len = len_ref[pl.program_id(0)]          # scalar from SMEM
     live = ik * block_k < cache_len
 
     @pl.when(live)
@@ -48,26 +49,29 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
             jnp.int32, logits.shape, 1)
         mask = cols < cache_len
         logits = jnp.where(mask, logits, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(logits, axis=1))
+        # m/l scratch hold each row's value in every lane; column 0 is read
+        m_prev = m_ref[:, :1]                      # (group, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(logits, axis=1, keepdims=True))
         corr = jnp.exp(m_prev - m_new)
-        p = jnp.where(mask, jnp.exp(logits - m_new[:, None]), 0.0)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1)
-        acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot_general(
+        p = jnp.where(mask, jnp.exp(logits - m_new), 0.0)
+        l_new = l_ref[:, :1] * corr + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
 
     @pl.when(ik == num_kv_blocks - 1)
     def _finalize():
-        out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-20)[:, None]
+        out = acc_ref[...] / jnp.maximum(l_ref[:, :1], 1e-20)
         o_ref[0, 0] = out.astype(o_ref.dtype)
 
 
 def flash_decode(q, k_cache, v_cache, cache_len, *,
-                 block_k: int = DEFAULT_BLOCK_K, interpret: bool = True):
+                 block_k: int = DEFAULT_BLOCK_K, interpret: bool):
     """q: (b, h, d); caches: (b, kh, S, d); cache_len: (b,) int32.
-    Returns (b, h, d)."""
+    Returns (b, h, d).  ``cache_len`` is scalar-prefetched into SMEM;
+    ``interpret=True`` runs the Pallas interpreter (CPU tests)."""
     b, h, d = q.shape
     kh, S = k_cache.shape[1], k_cache.shape[2]
     assert h % kh == 0
@@ -82,23 +86,25 @@ def flash_decode(q, k_cache, v_cache, cache_len, *,
                                block_k=block_k, num_kv_blocks=nk)
     out = pl.pallas_call(
         kernel,
-        grid=(b, kh, nk),
-        in_specs=[
-            pl.BlockSpec((1,), lambda ib, ih, ik: (ib,)),
-            pl.BlockSpec((1, 1, group, d), lambda ib, ih, ik: (ib, ih, 0, 0)),
-            pl.BlockSpec((1, 1, block_k, d),
-                         lambda ib, ih, ik: (ib, ih, ik, 0)),
-            pl.BlockSpec((1, 1, block_k, d),
-                         lambda ib, ih, ik: (ib, ih, ik, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, group, d),
-                               lambda ib, ih, ik: (ib, ih, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, kh, nk),
+            in_specs=[
+                pl.BlockSpec((1, 1, group, d),
+                             lambda ib, ih, ik, _: (ib, ih, 0, 0)),
+                pl.BlockSpec((1, 1, block_k, d),
+                             lambda ib, ih, ik, _: (ib, ih, ik, 0)),
+                pl.BlockSpec((1, 1, block_k, d),
+                             lambda ib, ih, ik, _: (ib, ih, ik, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, 1, group, d),
+                                   lambda ib, ih, ik, _: (ib, ih, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((group, d), jnp.float32),
+                pltpu.VMEM((group, LANES), jnp.float32),
+                pltpu.VMEM((group, LANES), jnp.float32),
+            ]),
         out_shape=jax.ShapeDtypeStruct((b, kh, group, d), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((group, d), jnp.float32),
-            pltpu.VMEM((group,), jnp.float32),
-            pltpu.VMEM((group,), jnp.float32),
-        ],
         interpret=interpret,
-    )(cache_len, qg, k_cache, v_cache)
+    )(jnp.asarray(cache_len, jnp.int32), qg, k_cache, v_cache)
     return out.reshape(b, h, d)

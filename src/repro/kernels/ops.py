@@ -1,8 +1,7 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-``interpret`` defaults to True because this container is CPU-only; on a
-real TPU, pass interpret=False (or set ModelConfig.use_pallas) and the
-same BlockSpecs lower to Mosaic.
+``interpret`` has no default: ``True`` runs the Pallas interpreter (the
+CPU tests), ``False`` lowers the same BlockSpecs to Mosaic on the TPU.
 """
 from __future__ import annotations
 
@@ -20,7 +19,7 @@ from repro.kernels.packed_attention import packed_flash_attention
                                              "interpret", "block_q",
                                              "block_k"))
 def packed_attention(q, k, v, q_seg, kv_seg, *, causal: bool = True,
-                     use_pallas: bool = True, interpret: bool = True,
+                     use_pallas: bool = True, interpret: bool,
                      block_q: int = 128, block_k: int = 128):
     """Layout: q (b, h, sq, d); k/v (b, kh, sk, d); segs (b, s)."""
     if not use_pallas:
@@ -34,7 +33,7 @@ def packed_attention(q, k, v, q_seg, kv_seg, *, causal: bool = True,
 @functools.partial(jax.jit, static_argnames=("use_pallas", "interpret",
                                              "block_k"))
 def decode_attention(q, k_cache, v_cache, cache_len, *,
-                     use_pallas: bool = True, interpret: bool = True,
+                     use_pallas: bool = True, interpret: bool,
                      block_k: int = 256):
     """Layout: q (b, h, d); caches (b, kh, S, d); cache_len (b,)."""
     if not use_pallas:

@@ -54,16 +54,21 @@ def flash_decode_ref(q, k_cache, v_cache, cache_len):
 
 
 def wkv6_ref(r, k, v, loga, u, reset):
-    """Sequential WKV6 oracle.  r,k,v,loga: (b, s, h, dk) fp32; u: (h, dk);
-    reset: (b, s) bool.  Returns (b, s, h, dk)."""
+    """Sequential WKV6 oracle (one scan step per token).  r,k,v,loga:
+    (b, s, h, dk) fp32; u: (h, dk); reset: (b, s) bool.  Returns
+    (b, s, h, dk)."""
     b, s, h, dk = r.shape
-    S = jnp.zeros((b, h, dk, dk), jnp.float32)
-    outs = []
-    for t in range(s):
-        S = jnp.where(reset[:, t, None, None, None], 0.0, S)
-        kv = jnp.einsum("bhi,bhj->bhij", k[:, t], v[:, t])
-        o = jnp.einsum("bhi,bhij->bhj", r[:, t],
-                       S + u[None, :, :, None] * kv)
-        outs.append(o)
-        S = S * jnp.exp(loga[:, t])[..., None] + kv
-    return jnp.stack(outs, axis=1)
+
+    def step(S, xs):
+        r_t, k_t, v_t, la_t, rst_t = xs
+        S = jnp.where(rst_t[:, None, None, None], 0.0, S)
+        kv = jnp.einsum("bhi,bhj->bhij", k_t, v_t)
+        o = jnp.einsum("bhi,bhij->bhj", r_t, S + u[None, :, :, None] * kv)
+        return S * jnp.exp(la_t)[..., None] + kv, o
+
+    t_major = lambda a: jnp.moveaxis(jnp.asarray(a), 1, 0)
+    _, outs = jax.lax.scan(
+        step, jnp.zeros((b, h, dk, dk), jnp.float32),
+        (t_major(r), t_major(k), t_major(v), t_major(loga),
+         t_major(reset)))
+    return jnp.moveaxis(outs, 0, 1)

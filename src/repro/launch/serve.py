@@ -24,9 +24,6 @@ def main():
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--gen", type=int, default=16)
-    ap.add_argument("--use-pallas", action="store_true",
-                    help="route attention through the Pallas kernels "
-                         "(interpret mode on CPU)")
     args = ap.parse_args()
 
     if args.reduced:

@@ -5,22 +5,20 @@ restart resumes both consistently).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import os
 import pickle
 import time
-from typing import Optional
+from typing import Optional, Sequence
 
 import jax
-import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.configs.base import ModelConfig
 from repro.core.orchestrator import Overlord
-from repro.models.model_zoo import Model, build_model
+from repro.models.model_zoo import Model
 from repro.train.optimizer import AdamWConfig
-from repro.train.train_step import (
-    TrainState, init_train_state, make_train_step,
-)
+from repro.train.train_step import init_train_state, make_train_step
 
 
 @dataclasses.dataclass
@@ -32,25 +30,46 @@ class TrainerConfig:
     opt: AdamWConfig = dataclasses.field(default_factory=AdamWConfig)
 
 
+def data_parallel_step(model: Model, opt: AdamWConfig, mesh: Mesh):
+    """The jitted train step over a 1-D ``("data",)`` mesh: batch rows are
+    split over the mesh, the train state is replicated and donated (so
+    params and Adam moments are not double-allocated at peak).  XLA puts
+    in the gradient all-reduce; on one device it is the plain step."""
+    repl = NamedSharding(mesh, P())
+    return jax.jit(make_train_step(model, opt),
+                   in_shardings=(repl, NamedSharding(mesh, P("data"))),
+                   out_shardings=repl, donate_argnums=(0,))
+
+
 class Trainer:
     """Single-process trainer consuming OVERLORD batches.
 
-    On a real pod-slice every host runs this loop; per-host constructors
-    feed local shards and `jax.make_array_from_process_local_data` forms
-    the global arrays.  On this one-device container the loop assembles
-    the global batch from all buckets directly.
+    Every data-fetching client's rows are concatenated in rank order into
+    one global batch, which is placed over a 1-D ``("data",)`` mesh of
+    ``devices`` (default: all of ``jax.devices()``): with one device per
+    DP rank, each rank's rows land on its own chip.  The train state is
+    replicated.  A multi-host slice would build the same global array with
+    ``jax.make_array_from_process_local_data`` from per-host constructors.
     """
 
     def __init__(self, model: Model, overlord: Overlord,
-                 cfg: TrainerConfig = TrainerConfig(), seed: int = 0):
+                 cfg: TrainerConfig = TrainerConfig(), seed: int = 0,
+                 devices: Optional[Sequence] = None):
         self.model = model
         self.ov = overlord
         self.cfg = cfg
-        self.state = init_train_state(model, jax.random.key(seed))
-        self.step_fn = jax.jit(make_train_step(model, cfg.opt))
+        self.mesh = Mesh(np.array(devices or jax.devices()), ("data",))
+        self.batch_sharding = NamedSharding(self.mesh, P("data"))
+        self.state = jax.jit(
+            functools.partial(init_train_state, model),
+            out_shardings=NamedSharding(self.mesh, P()))(
+                jax.random.key(seed))
+        self.step_fn = data_parallel_step(model, cfg.opt, self.mesh)
+        self._compiled = None
+        self.compile_s: Optional[float] = None
         self.history: list[dict] = []
 
-    def _assemble_global_batch(self, step: int) -> dict:
+    def fetch(self, step: int) -> dict:
         """Pull every data-fetching client's view; concatenate bucket/bin
         rows into the global batch."""
         axis = self.ov.cfg.strategy_params.get("axis", "DP")
@@ -65,27 +84,46 @@ class Trainer:
         seg = np.concatenate([p.segment_ids for p in parts], 0)
         pos = np.concatenate([p.positions for p in parts], 0)
         labels = np.concatenate([p.labels for p in parts], 0)
+        if len(tokens) % self.mesh.size:
+            raise ValueError(f"{len(tokens)} global rows do not split over "
+                             f"{self.mesh.size} devices")
         return {"tokens": tokens, "segment_ids": seg, "positions": pos,
                 "labels": labels}
+
+    def step(self, batch: dict) -> dict:
+        """One train step on a host batch; returns its metrics as floats.
+        ``step_s`` covers the host-to-device copy and the step, and ends
+        when the device has finished it.  The first call compiles (timed
+        apart as ``compile_s``)."""
+        if self._compiled is None:
+            t0 = time.perf_counter()
+            self._compiled = self.step_fn.lower(self.state, batch).compile()
+            self.compile_s = time.perf_counter() - t0
+        t1 = time.perf_counter()
+        self.state, metrics = self._compiled(
+            self.state, jax.device_put(batch, self.batch_sharding))
+        jax.block_until_ready(self.state)
+        rec = {k: float(v) for k, v in jax.device_get(metrics).items()}
+        rec["step_s"] = time.perf_counter() - t1
+        return rec
 
     def train(self, steps: Optional[int] = None) -> list[dict]:
         steps = steps or self.cfg.steps
         for step in range(steps):
-            t0 = time.time()
-            batch = self._assemble_global_batch(step)
-            fetch_s = time.time() - t0
-            t1 = time.time()
-            self.state, metrics = self.step_fn(self.state, batch)
-            loss = float(metrics["loss"])
-            rec = {"step": step, "loss": loss,
-                   "accuracy": float(metrics["accuracy"]),
-                   "grad_norm": float(metrics["grad_norm"]),
-                   "fetch_s": round(fetch_s, 4),
-                   "step_s": round(time.time() - t1, 4)}
+            t0 = time.perf_counter()
+            batch = self.fetch(step)
+            fetch_s = time.perf_counter() - t0
+            m = self.step(batch)
+            rec = {"step": step, "loss": m["loss"],
+                   "accuracy": m["accuracy"], "grad_norm": m["grad_norm"],
+                   "tokens": m["tokens"],
+                   "host_tokens": int(np.sum((batch["labels"] >= 0)
+                                             & (batch["segment_ids"] > 0))),
+                   "fetch_s": fetch_s, "step_s": m["step_s"]}
             self.history.append(rec)
-            self.ov.step_done(step, {"loss": loss})
+            self.ov.step_done(step, {"loss": rec["loss"]})
             if step % self.cfg.log_every == 0:
-                print(f"step {step:5d} loss {loss:8.4f} "
+                print(f"step {step:5d} loss {rec['loss']:8.4f} "
                       f"acc {rec['accuracy']:.3f} "
                       f"fetch {fetch_s*1e3:6.1f}ms "
                       f"step {rec['step_s']*1e3:7.1f}ms", flush=True)
@@ -111,5 +149,5 @@ class Trainer:
         treedef = jax.tree.structure(self.state)
         leaves = jax.tree.leaves(self.state)
         self.state = jax.tree.unflatten(
-            treedef, [jnp.asarray(a, l.dtype)
+            treedef, [jax.device_put(np.asarray(a, l.dtype), l.sharding)
                       for a, l in zip(flat, leaves)])

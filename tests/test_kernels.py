@@ -42,7 +42,7 @@ def test_packed_attention_sweep(b, h, kh, s, d, dtype, causal):
     v = jnp.asarray(rng.normal(size=(b, kh, s, d)), dtype)
     seg = _segs(b, s)
     out = packed_flash_attention(q, k, v, seg, seg, causal=causal,
-                                 block_q=128, block_k=128)
+                                 block_q=128, block_k=128, interpret=True)
     exp = ref.packed_attention_ref(q, k, v, seg, seg, causal=causal)
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(exp, np.float32),
@@ -57,10 +57,12 @@ def test_packed_attention_blocks_cross_segment_leakage():
     v = np.asarray(rng.normal(size=(b, h, s, d)), np.float32)
     seg = np.ones((b, s), np.int32)
     seg[:, 64:] = 2
-    out1 = packed_flash_attention(q, k, jnp.asarray(v), seg, seg)
+    out1 = packed_flash_attention(q, k, jnp.asarray(v), seg, seg,
+                                  interpret=True)
     v2 = v.copy()
     v2[:, :, 64:, :] = 0.0  # nuke segment 2's values
-    out2 = packed_flash_attention(q, k, jnp.asarray(v2), seg, seg)
+    out2 = packed_flash_attention(q, k, jnp.asarray(v2), seg, seg,
+                                  interpret=True)
     np.testing.assert_allclose(np.asarray(out1)[:, :, :64],
                                np.asarray(out2)[:, :, :64], atol=1e-6)
 
@@ -76,7 +78,7 @@ def test_flash_decode_sweep(b, h, kh, S, d, blk, dtype):
     kc = jnp.asarray(rng.normal(size=(b, kh, S, d)), dtype)
     vc = jnp.asarray(rng.normal(size=(b, kh, S, d)), dtype)
     clen = rng.integers(1, S, size=(b,)).astype(np.int32)
-    out = flash_decode(q, kc, vc, clen, block_k=blk)
+    out = flash_decode(q, kc, vc, clen, block_k=blk, interpret=True)
     exp = ref.flash_decode_ref(q, kc, vc, clen)
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(exp, np.float32),
@@ -98,7 +100,8 @@ def test_wkv6_sweep(b, h, s, dk, chunk):
     reset[:, 0] = True
     reset[0, s // 3] = True          # mid-chunk reset (regression: fp32
     reset[-1, s // 2 + 3] = True     # cancellation with -1e30 penalties)
-    out = wkv6_forward(r, k, v, loga, u, reset, chunk=chunk)
+    out = wkv6_forward(r, k, v, loga, u, reset, chunk=chunk,
+                       interpret=True)
     tr = lambda a: np.transpose(a, (0, 2, 1, 3))
     exp = ref.wkv6_ref(tr(r), tr(k), tr(v), tr(loga), u, reset)
     np.testing.assert_allclose(
