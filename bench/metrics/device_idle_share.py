@@ -1,0 +1,8 @@
+"""1 - (union of device op intervals) / (traced window), averaged over the
+chips, from the profiler trace, %."""
+
+
+def read(w):
+    if w.trace is None:
+        return None
+    return 100.0 * w.trace["idle_share"]

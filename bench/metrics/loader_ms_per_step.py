@@ -1,0 +1,7 @@
+"""Source loader busy time (``loader.refill`` + ``loader.prepare`` spans)
+per window step, in ms."""
+from bench.metrics._spans import ms_per_step
+
+
+def read(w):
+    return ms_per_step(w, "loader.refill", "loader.prepare")

@@ -1,0 +1,6 @@
+"""Planner time (``planner.plan_step`` spans) per window step, in ms."""
+from bench.metrics._spans import ms_per_step
+
+
+def read(w):
+    return ms_per_step(w, "planner.plan_step")
