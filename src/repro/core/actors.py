@@ -187,8 +187,7 @@ class ActorHandle:
             if retry is None:
                 return self._call_once(method, args, kwargs, timeout)
             return retry.run(self._call_once, method, args, kwargs, timeout)
-        t0 = time.perf_counter()
-        with tel.span("actor.call", actor=self.name, method=method):
+        with tel.span("actor.call", actor=self.name, method=method) as sp:
             tel.inc("actor_calls_total", 1.0, actor=self.name,
                     method=method)
             try:
@@ -204,8 +203,7 @@ class ActorHandle:
                 tel.inc("actor_call_failures_total", 1.0, actor=self.name,
                         method=method)
                 raise
-        tel.observe("actor_call_seconds", time.perf_counter() - t0,
-                    method=method)
+        tel.observe("actor_call_seconds", sp.duration, method=method)
         return result
 
     def _call_once(self, method: str, args: tuple, kwargs: dict,

@@ -411,8 +411,7 @@ class Overlord:
 
     def get_batch(self, step: int, rank: int, timeout: float = 60.0) -> dict:
         tel = self.telemetry
-        t0 = time.perf_counter()
-        with tel.span("overlord.get_batch", step=step, rank=rank):
+        with tel.span("overlord.get_batch", step=step, rank=rank) as sp:
             view = self.clients[rank].get(step, timeout=timeout)
             if view.get("role") == "data":
                 ids = {sid for b in view["bins"] for row in b.doc_ids
@@ -431,8 +430,7 @@ class Overlord:
                         self._delivered_ids |= new
                     if new:
                         tel.inc("delivered_samples_total", len(new))
-        tel.observe("get_batch_seconds", time.perf_counter() - t0,
-                    rank=rank)
+        tel.observe("get_batch_seconds", sp.duration, rank=rank)
         return view
 
     def step_done(self, step: int, metrics: Optional[dict] = None):

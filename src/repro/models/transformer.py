@@ -60,6 +60,9 @@ def lm_defs(cfg: ModelConfig) -> dict:
 
 
 # ----------------------------------------------------------------- blocks
+# The named scopes tag the blocks' HLO ops (op_name metadata), so a device
+# trace can group its time by attention, FFN, loss and optimizer.
+@jax.named_scope("attention")
 def _attn_block(lp, cfg, h, segment_ids, positions):
     x = L.rmsnorm(lp["attn_norm"], h, cfg.norm_eps)
     q, k, v = L.qkv_project(lp["attn"], cfg, x, positions)
@@ -71,6 +74,7 @@ def _attn_block(lp, cfg, h, segment_ids, positions):
     return L.attn_out_project(lp["attn"], attn)
 
 
+@jax.named_scope("ffn")
 def _ffn_block(lp, cfg, h, global_tokens):
     x = L.rmsnorm(lp["mlp_norm"], h, cfg.norm_eps)
     if "moe" in lp:

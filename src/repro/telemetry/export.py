@@ -3,7 +3,8 @@
   * ``render_prometheus`` — text exposition of a MetricsRegistry
     (counters/gauges as-is, histograms as summaries with quantiles).
   * ``chrome_trace`` / ``write_chrome_trace`` — span timeline in the
-    Chrome ``chrome://tracing`` / Perfetto JSON format.
+    Chrome ``chrome://tracing`` / Perfetto JSON format; each event's
+    ``tdur`` is the span's thread CPU time.
   * ``canonical_spans`` — deterministic, timestamp-stripped span TREE
     for golden-trace regression tests: ids, timestamps, durations and
     thread names are dropped; attrs survive (minus an optional strip
@@ -95,7 +96,7 @@ def chrome_trace(source) -> dict:
         events.append({
             "name": s.name, "cat": "repro", "ph": "X",
             "ts": s.start * 1e6, "dur": s.duration * 1e6,
-            "pid": 1, "tid": tid,
+            "tdur": s.cpu * 1e6, "pid": 1, "tid": tid,
             "args": {k: _sanitize(v) for k, v in s.attrs.items()},
         })
     meta = [{"name": "thread_name", "ph": "M", "pid": 1, "tid": tid,

@@ -4,8 +4,16 @@
 creates one, hands it to the ActorRuntime and to every actor it spawns,
 and every instrumentation site goes through it.  With ``enabled=False``
 all writers are no-ops and ``span()`` returns a shared null context
-manager, so the disabled overhead is one attribute check per site (the
-<= 5% budget benchmarks/orchestration.py:run_telemetry_overhead gates).
+manager, so the disabled overhead is one attribute check per site.  An
+enabled span costs two reads of each of its clocks (``perf_counter`` and
+``thread_time``) plus the mirror's annotation when one is set.  What that
+costs a training step is measured on the chip, by the benchmark's
+``train_tokens_per_s`` with and without the profiler (PERF.md), not by a
+CPU proxy.
+
+The trainer writes its loop spans (``trainer.*``) to the same plane, so a
+reader of ``tracer.finished()`` sees the loop and the data plane on one
+clock.
 
 ``NULL_TELEMETRY`` / ``ensure_telemetry`` give components a uniform
 "maybe instrumented" dependency without None checks at every site.

@@ -9,6 +9,17 @@ counted) and are exported as a Chrome-trace timeline or a canonical
 (timestamp-stripped) tree for golden tests — see ``repro.telemetry
 .export``.
 
+Each span also records ``cpu``: the CPU time its own thread spent
+between open and close (``time.thread_time``).  ``duration - cpu`` is the
+time the thread was off the CPU inside the span: waiting for the
+interpreter lock, for the accelerator runtime, or for I/O.
+
+A tracer can hold a ``mirror``: a callable ``mirror(name, **attrs)``
+returning a context manager that is entered while each span is open.  The
+trainer hands it ``jax.profiler.TraceAnnotation``, so every span of every
+thread lands on the profiler's clock beside the device ops; this module
+itself imports no accelerator library.
+
 Attribution convention: every span carries the actor/source/step labels
 relevant at its layer (``actor.call`` -> actor+method, ``loader.*`` ->
 source, ``planner.*``/``constructor.*`` -> step/bucket), and chaos-
@@ -21,17 +32,20 @@ import itertools
 import threading
 import time
 from collections import deque
-from typing import Optional
+from typing import Callable, Optional
 
 
 class Span:
-    """One finished or in-flight span.  Mutate attrs via ``set_attr``."""
+    """One finished or in-flight span.  Mutate attrs via ``set_attr``.
+    ``start``/``end`` are on the tracer's clock; ``cpu`` is the thread CPU
+    seconds between open and close (0 while in flight)."""
 
     __slots__ = ("span_id", "parent_id", "name", "attrs", "thread",
-                 "start", "end")
+                 "start", "end", "cpu", "_cpu_start")
 
     def __init__(self, span_id: int, parent_id: Optional[int], name: str,
-                 attrs: dict, thread: str, start: float):
+                 attrs: dict, thread: str, start: float,
+                 cpu_start: float = 0.0):
         self.span_id = span_id
         self.parent_id = parent_id
         self.name = name
@@ -39,6 +53,8 @@ class Span:
         self.thread = thread
         self.start = start
         self.end: Optional[float] = None
+        self.cpu = 0.0
+        self._cpu_start = cpu_start
 
     def set_attr(self, key: str, value) -> None:
         self.attrs[key] = value
@@ -55,7 +71,7 @@ class Span:
         return {"span_id": self.span_id, "parent_id": self.parent_id,
                 "name": self.name, "attrs": dict(self.attrs),
                 "thread": self.thread, "start": self.start,
-                "end": self.end}
+                "end": self.end, "cpu": self.cpu}
 
     def __repr__(self) -> str:
         return f"Span({self.name!r}, id={self.span_id}, " \
@@ -67,6 +83,7 @@ class _NullSpan:
     context manager and as the span it would yield."""
 
     __slots__ = ()
+    duration = 0.0
 
     def __enter__(self) -> "_NullSpan":
         return self
@@ -85,19 +102,26 @@ NULL_SPAN = _NullSpan()
 
 
 class _SpanContext:
-    __slots__ = ("_tracer", "_name", "_attrs", "_span")
+    __slots__ = ("_tracer", "_name", "_attrs", "_span", "_mirror")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict):
         self._tracer = tracer
         self._name = name
         self._attrs = attrs
         self._span: Optional[Span] = None
+        self._mirror = None
 
     def __enter__(self) -> Span:
         self._span = self._tracer._open(self._name, self._attrs)
+        mirror = self._tracer.mirror
+        if mirror is not None:
+            self._mirror = mirror(self._name, **self._attrs)
+            self._mirror.__enter__()
         return self._span
 
     def __exit__(self, exc_type, exc, tb) -> bool:
+        if self._mirror is not None:
+            self._mirror.__exit__(exc_type, exc, tb)
         self._tracer._close(self._span, exc_type)
         return False
 
@@ -108,6 +132,8 @@ class Tracer:
     def __init__(self, max_spans: int = 65536,
                  clock=time.perf_counter):
         self._clock = clock
+        # mirror(name, **attrs) -> context manager, entered per span
+        self.mirror: Optional[Callable] = None
         self._lock = threading.Lock()
         self._max_spans = max(int(max_spans), 1)
         self._finished: deque = deque()
@@ -134,7 +160,8 @@ class Tracer:
             stack = self._local.stack = []
         parent = stack[-1].span_id if stack else None
         span = Span(next(self._ids), parent, name, attrs,
-                    threading.current_thread().name, self._clock())
+                    threading.current_thread().name, self._clock(),
+                    time.thread_time())
         stack.append(span)
         return span
 
@@ -142,6 +169,7 @@ class Tracer:
         if span is None:
             return
         span.end = self._clock()
+        span.cpu = time.thread_time() - span._cpu_start
         if exc_type is not None:
             span.attrs.setdefault("error", exc_type.__name__)
         stack = getattr(self._local, "stack", None)

@@ -61,10 +61,11 @@ def make_loss_fn(model: Model):
         cparams = _cast_for_compute(params)
         logits, aux = model.forward(cparams, batch)
         labels = batch["labels"]
-        mask = ((labels >= 0) & (batch["segment_ids"] > 0)).astype(
-            jnp.float32)
-        loss, acc = cross_entropy(logits, labels, mask)
-        total = loss + AUX_LOSS_WEIGHT * aux
+        with jax.named_scope("loss"):
+            mask = ((labels >= 0) & (batch["segment_ids"] > 0)).astype(
+                jnp.float32)
+            loss, acc = cross_entropy(logits, labels, mask)
+            total = loss + AUX_LOSS_WEIGHT * aux
         return total, {"loss": loss, "aux_loss": aux, "accuracy": acc,
                        "tokens": jnp.sum(mask)}
     return loss_fn
@@ -76,8 +77,9 @@ def make_train_step(model: Model, opt_cfg: AdamWConfig = AdamWConfig()):
     def train_step(state: TrainState, batch):
         (total, metrics), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(state.params, batch)
-        new_params, new_opt, opt_metrics = adamw_update(
-            opt_cfg, grads, state.opt, state.params)
+        with jax.named_scope("optimizer"):
+            new_params, new_opt, opt_metrics = adamw_update(
+                opt_cfg, grads, state.opt, state.params)
         metrics = dict(metrics, total_loss=total, **opt_metrics)
         return TrainState(new_params, new_opt), metrics
 
