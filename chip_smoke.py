@@ -12,8 +12,9 @@ over 2 DP ranks.  One warm-up step, then timed steps; each must give a
 finite loss and grad norm, and the device's token count must equal the
 host's count of ``labels >= 0 & segment_ids > 0`` in the delivered batch.
 The kernel phase compiles each Pallas kernel for the chip
-(``interpret=False``) at real widths and compares it with kernels/ref.py
-at ``highest`` matmul precision.
+(``interpret=False``) at real widths and compares it, and the packed
+attention's gradients, with kernels/ref.py at ``highest`` matmul
+precision.
 
 Four chips.  The same global batches train 3 steps over a 4-device
 ``("data",)`` mesh and then on the first device alone; the losses and a
@@ -189,7 +190,32 @@ def kernel_phase() -> list[str]:
         "packed_attention", out, exp, 2e-2, 2e-2,
         "bf16 output: rounding is 2^-8 relative on O(1) values; the bound "
         "the interpret-mode tests hold bf16 to")
-    del q, k, v, out, exp
+    del out, exp
+    # its backward: gradients in q, k, v for a random cotangent
+    w = normal((b, h, s, d), jnp.float32)
+
+    def attn_grads(q, k, v, q_seg, kv_seg, w, interpret):
+        loss = lambda q, k, v: jnp.sum(packed_flash_attention(
+            q, k, v, q_seg, kv_seg, interpret=interpret
+        ).astype(jnp.float32) * w)
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    got = _compiled(attn_grads, q, k, v, seg, seg, w)(q, k, v, seg, seg, w)
+    with jax.default_matmul_precision("highest"):
+        ref_grads = jax.jit(jax.grad(
+            lambda q, k, v, w: jnp.sum(ref.packed_attention_ref(
+                q, k, v, seg, seg).astype(jnp.float32) * w),
+            argnums=(0, 1, 2)))
+        parts = [ref_grads(q[:, i * g:(i + 1) * g], k[:, i:i + 1],
+                           v[:, i:i + 1], w[:, i * g:(i + 1) * g])
+                 for i in range(kh)]
+    for i, name in enumerate(("dq", "dk", "dv")):
+        failures += _compare(
+            f"packed_attention_{name}", got[i],
+            jnp.concatenate([p[i] for p in parts], axis=1), 2e-2, 2e-2,
+            "bf16 gradients of f32 accumulations: rounding is 2^-8 "
+            "relative; the bound the interpret-mode tests hold bf16 to")
+    del q, k, v, w, got, parts
 
     # wkv6: rwkv6-3b, 40 heads x 64, one 4096 row with packed resets
     b, h, s, dk = 1, 40, 4096, 64

@@ -50,7 +50,6 @@ class ModelConfig:
     scan_layers: bool = True
     norm_eps: float = 1e-6
     tied_embeddings: bool = False
-    use_pallas: bool = False     # TPU-only: select Pallas kernel paths
 
     def resolved_head_dim(self) -> int:
         return self.head_dim or self.d_model // self.num_heads

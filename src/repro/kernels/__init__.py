@@ -1,3 +1,6 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas TPU kernels, each with a pure-jnp oracle in ``ref.py``.
+
+``packed_attention`` is on the training path (``models/attention.py``
+selects it on a TPU); ``wkv6`` and ``flash_decode`` are reached from the
+tests and ``chip_smoke.py``.
+"""

@@ -1,26 +1,37 @@
 """Attention: segment-aware (packed) online-softmax attention.
 
-Three entry points:
-  * ``segment_attention``         — chunked online-softmax (flash-style) over
-                                    KV blocks; the training/prefill path, and
-                                    the lowering reference for the Pallas
-                                    kernel in kernels/packed_attention.py.
+Entry points:
+  * ``segment_attention``         — the training/prefill path.  On a TPU,
+                                    for self-attention over lengths that are
+                                    a multiple of the kernel's block, it runs
+                                    the tile-skipping Pallas kernel
+                                    (kernels/packed_attention.py, forward and
+                                    backward); every other call runs
+                                    ``chunked_segment_attention``.
+  * ``chunked_segment_attention`` — chunked online-softmax (flash-style) over
+                                    KV blocks in jnp: the fallback, and the
+                                    reference the kernel is tested against.
   * ``full_segment_attention``    — unchunked oracle (tests / tiny configs).
+  * ``attention_tiles``           — the (query-block, key-block) tiles the
+                                    chosen path computes, live and in all.
   * ``decode_attention``          — one-token step against a (possibly
                                     sequence-sharded) KV cache.
 
 Packing semantics: segment id 0 marks padding; q attends to k iff
 ``seg_q == seg_k != 0`` and (causal) buffer index ``k <= q``.  This is
 exactly the workload the OVERLORD planner balances: per-microbatch FLOPs
-are proportional to sum(l_i^2) over packed segments.
+are proportional to sum(l_i^2) over packed segments, which is what the
+kernel computes.
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+from repro.kernels import packed_attention as pk
 
 NEG_INF = -1e30
 
@@ -67,14 +78,86 @@ def full_segment_attention(q, k, v, q_seg, kv_seg, *, causal: bool = True,
     return jnp.where(valid, out, 0.0).astype(q.dtype)
 
 
+# The kernel keeps a row's K/V (and, backward, Q/dO) of one head resident in
+# VMEM; longer rows take the jnp path.
+KERNEL_MAX_LEN = 16384
+
+
+def _kernel_takes(sq: int, sk: int, q_offset: int) -> bool:
+    """Whether a call's shapes suit the kernel: self-attention over whole
+    rows whose length is a multiple of its block."""
+    return (sq == sk and q_offset == 0 and sq % pk.DEFAULT_BLOCK_Q == 0
+            and sq <= KERNEL_MAX_LEN)
+
+
+def kernel_attention(q, k, v, q_seg, kv_seg, *, causal: bool = True,
+                     interpret: bool):
+    """The Pallas kernel in the models' layout: q (b, s, h, d); k, v
+    (b, s, kh, d).  Under a mesh with a ``data`` axis (the trainer's, see
+    ``train/trainer.py: data_parallel_step``) each device runs it on its own
+    rows, so no device gathers another's q, k or v."""
+    def run(q, k, v, q_seg, kv_seg):
+        t = lambda x: x.transpose(0, 2, 1, 3)
+        return t(pk.packed_flash_attention(
+            t(q), t(k), t(v), q_seg, kv_seg, causal=causal,
+            interpret=interpret))
+
+    mesh = jax.sharding.get_abstract_mesh()
+    n = dict(mesh.shape).get("data", 1) if not mesh.empty else 1
+    if n > 1 and q.shape[0] % n == 0:
+        # check_vma off: pallas_call's outputs carry no varying-axes type
+        run = jax.shard_map(run, mesh=mesh, in_specs=(P("data"),) * 5,
+                            out_specs=P("data"), check_vma=False)
+    return run(q, k, v, q_seg, kv_seg)
+
+
 def segment_attention(q, k, v, q_seg, kv_seg, *, causal: bool = True,
                       chunk: int = 1024, q_offset: int = 0):
-    """Chunked online-softmax attention over KV blocks.
+    """Packed attention.  q: (b, sq, h, d); k, v: (b, sk, kh, d), GQA kv
+    heads unexpanded.  The Pallas kernel where the lowering platform is a TPU
+    and ``_kernel_takes`` the shapes; ``chunked_segment_attention`` (KV
+    chunks of ``chunk``) everywhere else."""
+    jnp_path = functools.partial(chunked_segment_attention, causal=causal,
+                                 chunk=chunk, q_offset=q_offset)
+    if not _kernel_takes(q.shape[1], k.shape[1], q_offset):
+        return jnp_path(q, k, v, q_seg, kv_seg)
+    return jax.lax.platform_dependent(
+        q, k, v, q_seg, kv_seg,
+        tpu=functools.partial(kernel_attention, causal=causal,
+                              interpret=False),
+        default=jnp_path)
 
-    Flash-attention memory profile on the jnp path: per-step logits are
-    (b, h, sq, chunk); the scan body is rematerialized in the backward pass.
+
+def attention_tiles(segment_ids):
+    """(live, total) (query-block, key-block) tiles of the causal
+    self-attention over a batch's packed rows (b, s), per head and layer, on
+    the path ``segment_attention`` takes: the kernel's live ranges, or every
+    tile on the jnp path.  int32 scalars."""
+    b, s = segment_ids.shape
+    n = -(-s // pk.DEFAULT_BLOCK_Q)
+    total = jnp.int32(b * n * n)
+    if not _kernel_takes(s, s, 0):
+        return total, total
+    live = jax.lax.platform_dependent(
+        segment_ids,
+        tpu=lambda seg: pk.live_tile_count(seg, seg, causal=True),
+        default=lambda seg: total)
+    return live, total
+
+
+def chunked_segment_attention(q, k, v, q_seg, kv_seg, *,
+                              causal: bool = True, chunk: int = 1024,
+                              q_offset: int = 0):
+    """Chunked online-softmax attention over KV blocks, in jnp.
+    q: (b, sq, h, d); k, v: (b, sk, kh, d).
+
+    Flash-attention memory profile: per-step logits are (b, h, sq, chunk);
+    the scan body is rematerialized in the backward pass.  Every tile is
+    computed, live or not.
     """
     b, sq, h, d = q.shape
+    k = expand_kv(k, h)
+    v = expand_kv(v, h)
     sk = k.shape[1]
     chunk = min(chunk, sk)
     if sk % chunk != 0:

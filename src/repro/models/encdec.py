@@ -14,7 +14,7 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.models import layers as L
 from repro.models.attention import (
-    decode_attention, expand_kv, segment_attention,
+    decode_attention, segment_attention,
 )
 from repro.models.params import EMBED, VOCAB, ParamDef, stacked
 from repro.sharding.logical import shard
@@ -58,8 +58,6 @@ def encode(params, cfg: ModelConfig, enc_embeds: jax.Array) -> jax.Array:
     def layer_fn(h, lp):
         x = L.layernorm(lp["attn_norm"], h, cfg.norm_eps)
         q, k, v = L.qkv_project(lp["attn"], cfg, x, pos)
-        k = expand_kv(k, cfg.num_heads)
-        v = expand_kv(v, cfg.num_heads)
         attn = segment_attention(q, k, v, ones, ones, causal=False,
                                  chunk=cfg.attn_chunk)
         h = h + L.attn_out_project(lp["attn"], attn)
@@ -78,8 +76,6 @@ def _cross_block(lp, cfg, h, enc_out, enc_valid):
     q = jnp.einsum("bsd,dhk->bshk", x, lp["cross"]["wq"])
     k = jnp.einsum("bsd,dhk->bshk", enc_out, lp["cross"]["wk"])
     v = jnp.einsum("bsd,dhk->bshk", enc_out, lp["cross"]["wv"])
-    k = expand_kv(k, cfg.num_heads)
-    v = expand_kv(v, cfg.num_heads)
     b, s = x.shape[:2]
     q_seg = jnp.ones((b, s), jnp.int32)
     attn = segment_attention(q, k, v, q_seg, enc_valid, causal=False,
@@ -98,8 +94,6 @@ def forward(params, cfg: ModelConfig, batch):
     def layer_fn(h, lp):
         x = L.layernorm(lp["attn_norm"], h, cfg.norm_eps)
         q, k, v = L.qkv_project(lp["attn"], cfg, x, pos)
-        k = expand_kv(k, cfg.num_heads)
-        v = expand_kv(v, cfg.num_heads)
         attn = segment_attention(q, k, v, seg, seg, causal=True,
                                  chunk=cfg.attn_chunk)
         h = h + L.attn_out_project(lp["attn"], attn)
@@ -161,18 +155,15 @@ def prefill(params, cfg: ModelConfig, batch):
         lp, xk, xv = xs
         x = L.layernorm(lp["attn_norm"], h, cfg.norm_eps)
         q, k, v = L.qkv_project(lp["attn"], cfg, x, pos)
-        ke = expand_kv(k, cfg.num_heads)
-        ve = expand_kv(v, cfg.num_heads)
-        attn = segment_attention(q, ke, ve, seg, seg, causal=True,
+        attn = segment_attention(q, k, v, seg, seg, causal=True,
                                  chunk=cfg.attn_chunk)
         h = h + L.attn_out_project(lp["attn"], attn)
         x = L.layernorm(lp["cross_norm"], h, cfg.norm_eps)
         q = jnp.einsum("bsd,dhk->bshk", x, lp["cross"]["wq"])
-        xke = expand_kv(xk.astype(q.dtype), cfg.num_heads)
-        xve = expand_kv(xv.astype(q.dtype), cfg.num_heads)
         q_seg = jnp.ones(x.shape[:2], jnp.int32)
-        cattn = segment_attention(q, xke, xve, q_seg, enc_valid,
-                                  causal=False, chunk=cfg.attn_chunk)
+        cattn = segment_attention(q, xk.astype(q.dtype), xv.astype(q.dtype),
+                                  q_seg, enc_valid, causal=False,
+                                  chunk=cfg.attn_chunk)
         h = h + L.attn_out_project(lp["cross"], cattn)
         x = L.layernorm(lp["mlp_norm"], h, cfg.norm_eps)
         h = h + L.gelu_mlp(lp["mlp"], x)

@@ -12,8 +12,7 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.models import layers as L
 from repro.models import ssm
-from repro.models.attention import decode_attention, expand_kv, \
-    segment_attention
+from repro.models.attention import decode_attention, segment_attention
 from repro.models.params import EMBED, VOCAB, ParamDef, stacked
 from repro.sharding.logical import shard
 
@@ -57,8 +56,6 @@ def _mamba_layer(lp, cfg, h, seg):
 def _shared_attn_apply(sp, cfg, h, seg, pos):
     x = L.rmsnorm(sp["attn_norm"], h, cfg.norm_eps)
     q, k, v = L.qkv_project(sp["attn"], cfg, x, pos)
-    k = expand_kv(k, cfg.num_heads)
-    v = expand_kv(v, cfg.num_heads)
     attn = segment_attention(q, k, v, seg, seg, causal=True,
                              chunk=cfg.attn_chunk)
     h = h + L.attn_out_project(sp["attn"], attn)
@@ -107,9 +104,7 @@ def prefill(params, cfg: ModelConfig, batch):
         h, states = jax.lax.scan(inner, h, bp)
         x = L.rmsnorm(sp["attn_norm"], h, cfg.norm_eps)
         q, k, v = L.qkv_project(sp["attn"], cfg, x, pos)
-        ke = expand_kv(k, cfg.num_heads)
-        ve = expand_kv(v, cfg.num_heads)
-        attn = segment_attention(q, ke, ve, seg, seg, causal=True,
+        attn = segment_attention(q, k, v, seg, seg, causal=True,
                                  chunk=cfg.attn_chunk)
         h = h + L.attn_out_project(sp["attn"], attn)
         x = L.rmsnorm(sp["mlp_norm"], h, cfg.norm_eps)

@@ -16,7 +16,7 @@ from repro.configs.base import ModelConfig
 from repro.models import layers as L
 from repro.models import moe as moe_lib
 from repro.models.attention import (
-    decode_attention, expand_kv, segment_attention,
+    decode_attention, segment_attention,
 )
 from repro.models.params import (
     EMBED, VOCAB, ParamDef, stacked,
@@ -66,8 +66,6 @@ def lm_defs(cfg: ModelConfig) -> dict:
 def _attn_block(lp, cfg, h, segment_ids, positions):
     x = L.rmsnorm(lp["attn_norm"], h, cfg.norm_eps)
     q, k, v = L.qkv_project(lp["attn"], cfg, x, positions)
-    k = expand_kv(k, cfg.num_heads)
-    v = expand_kv(v, cfg.num_heads)
     attn = segment_attention(q, k, v, segment_ids, segment_ids,
                              causal=True, chunk=cfg.attn_chunk)
     attn = shard(attn, "batch", "seq", "act_heads", None)
@@ -147,9 +145,7 @@ def prefill(params, cfg: ModelConfig, batch):
     def layer_fn(h, lp):
         x = L.rmsnorm(lp["attn_norm"], h, cfg.norm_eps)
         q, k, v = L.qkv_project(lp["attn"], cfg, x, pos)
-        ke = expand_kv(k, cfg.num_heads)
-        ve = expand_kv(v, cfg.num_heads)
-        attn = segment_attention(q, ke, ve, seg, seg, causal=True,
+        attn = segment_attention(q, k, v, seg, seg, causal=True,
                                  chunk=cfg.attn_chunk)
         h = h + L.attn_out_project(lp["attn"], attn)
         ffn, _ = _ffn_block(lp, cfg, h, b * s)

@@ -9,6 +9,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models import params as pdefs
+from repro.models.attention import attention_tiles
 from repro.models.model_zoo import Model
 from repro.train.optimizer import (
     AdamWConfig, AdamWState, adamw_update, init_adamw,
@@ -66,8 +67,12 @@ def make_loss_fn(model: Model):
                 jnp.float32)
             loss, acc = cross_entropy(logits, labels, mask)
             total = loss + AUX_LOSS_WEIGHT * aux
-        return total, {"loss": loss, "aux_loss": aux, "accuracy": acc,
-                       "tokens": jnp.sum(mask)}
+        metrics = {"loss": loss, "aux_loss": aux, "accuracy": acc,
+                   "tokens": jnp.sum(mask)}
+        if model.cfg.family != "ssm":      # the ssm family has no attention
+            metrics["attn_tiles_live"], metrics["attn_tiles_total"] = \
+                attention_tiles(batch["segment_ids"])
+        return total, metrics
     return loss_fn
 
 

@@ -34,9 +34,17 @@ def data_parallel_step(model: Model, opt: AdamWConfig, mesh: Mesh):
     """The jitted train step over a 1-D ``("data",)`` mesh: batch rows are
     split over the mesh, the train state is replicated and donated (so
     params and Adam moments are not double-allocated at peak).  XLA puts
-    in the gradient all-reduce; on one device it is the plain step."""
+    in the gradient all-reduce; on one device it is the plain step.  The
+    step is traced under the mesh, so the attention kernel runs on each
+    device's own rows (``models/attention.py: kernel_attention``)."""
+    step = make_train_step(model, opt)
+
+    def on_mesh(state, batch):
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            return step(state, batch)
+
     repl = NamedSharding(mesh, P())
-    return jax.jit(make_train_step(model, opt),
+    return jax.jit(on_mesh,
                    in_shardings=(repl, NamedSharding(mesh, P("data"))),
                    out_shardings=repl, donate_argnums=(0,))
 
@@ -152,6 +160,9 @@ class Trainer:
                "accuracy": m["accuracy"], "grad_norm": m["grad_norm"],
                "tokens": m["tokens"], "host_tokens": m["host_tokens"],
                "fetch_s": fetch_s, "step_s": m["step_s"]}
+        # live and total attention tiles of the step (models with attention)
+        rec.update({k: m[k] for k in ("attn_tiles_live", "attn_tiles_total")
+                    if k in m})
         self.history.append(rec)
         self.ov.step_done(step, {"loss": rec["loss"]})
         if step % self.cfg.log_every == 0:

@@ -1,9 +1,11 @@
 """Compiles for a described TPU v5e, with no chip attached.
 
 The Pallas kernels at the widths ``chip_smoke.py`` runs them must lower to
-Mosaic (``tpu_custom_call``), and the donated qwen3-8b ``chip_share()``
-train step must fit one v5e's 16 GiB; the 4-device data-parallel step must
-compile with its gradient all-reduce.  Nothing runs: these catch what the
+Mosaic (``tpu_custom_call``; packed attention forward and backward), and
+the donated qwen3-8b ``chip_share()`` train step must carry the attention
+kernel and fit one v5e's 16 GiB; the 4-device data-parallel step must
+compile with its gradient all-reduce and run the kernel on each device's
+own rows, gathering no q, k or v.  Nothing runs: these catch what the
 chip's compiler would refuse, at no chip time.
 
 The topology is described inside a module fixture, never at import: only
@@ -47,7 +49,14 @@ def _kernel_args(name, sharding):
     bf16, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
     if name == "packed_attention":    # qwen3-8b: 32 q / 8 kv heads x 128
         from repro.kernels.packed_attention import packed_flash_attention
-        return packed_flash_attention, [
+
+        def fwd_bwd(q, k, v, q_seg, kv_seg, interpret):
+            def loss(q, k, v):
+                return jnp.sum(packed_flash_attention(
+                    q, k, v, q_seg, kv_seg, interpret=interpret
+                ).astype(jnp.float32))
+            return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+        return fwd_bwd, [
             sds((1, 32, 4096, 128), bf16), sds((1, 8, 4096, 128), bf16),
             sds((1, 8, 4096, 128), bf16), sds((1, 4096), i32),
             sds((1, 4096), i32)]
@@ -91,14 +100,20 @@ def _peak_bytes(exe):
 
 
 def test_chip_share_train_step_fits_one_v5e(topo):
-    """The smoke run's step: 2 DP rows x 4096 tokens on one chip."""
+    """The smoke run's step: 2 DP rows x 4096 tokens on one chip, its
+    attention in the Pallas kernel."""
     exe = _compile_chip_share_step(topo.devices[:1], 2, 4096)
     assert exe.memory_analysis().alias_size_in_bytes > 0   # state donated
+    assert "tpu_custom_call" in exe.as_text()
     assert _peak_bytes(exe) < 0.9 * V5E_HBM_BYTES
 
 
 def test_data_parallel_step_compiles_for_four_chips(topo):
-    """The 4-chip check's step: 4 DP rows x 2048 tokens, one per chip."""
+    """The 4-chip check's step: 4 DP rows x 2048 tokens, one per chip; the
+    kernel runs on each chip's own row, so nothing is gathered."""
     exe = _compile_chip_share_step(topo.devices[:4], 4, 2048)
-    assert "all-reduce" in exe.as_text()
+    text = exe.as_text()
+    assert "all-reduce" in text
+    assert "tpu_custom_call" in text
+    assert "all-gather" not in text
     assert _peak_bytes(exe) < 0.9 * V5E_HBM_BYTES
