@@ -12,6 +12,7 @@ import dataclasses
 from typing import Callable
 
 from repro.configs.base import ModelConfig
+from repro.configs.rwkv6_3b import MIX_LORA
 
 
 @dataclasses.dataclass(frozen=True)
@@ -56,6 +57,12 @@ class EncoderCost:
 def active_params(cfg: ModelConfig) -> float:
     """Rough active-parameter count per token (backbone only)."""
     d, L = cfg.d_model, cfg.num_layers
+    if cfg.family == "ssm":
+        # RWKV6 time mix: r, k, v, g, o (5 d^2), the five token-shift LoRAs
+        # and the decay LoRA; channel mix: key and value (2 d d_ff) and the
+        # receptance (d^2)
+        loras = 5 * 2 * d * MIX_LORA + 2 * d * cfg.rwkv_lora_dim
+        return L * (5 * d * d + loras + 2 * d * cfg.d_ff + d * d)
     hd = cfg.resolved_head_dim()
     attn = d * (cfg.num_heads * hd + 2 * cfg.num_kv_heads * hd) \
         + cfg.num_heads * hd * d

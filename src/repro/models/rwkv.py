@@ -11,16 +11,18 @@ form, which overflows for fast decays).  The intra-chunk term is a fused
 (t, s, i) reduce; on TPU this is the Pallas wkv kernel's tile loop
 (kernels/wkv6.py), here it is the XLA reference path.
 
-Packing: segment starts inject a -1e30 log-decay *penalty* at the starting
-token, which zeroes any state influence crossing the boundary while leaving
-within-segment decays untouched.  Padding tokens (seg 0) contribute nothing
-to the state (their k is zeroed).
+Packing: a document starts with an empty state and a zero token shift, as
+at the start of a sequence.  The chunked scan counts segment starts and
+drops every (t, s) interaction and carried state that crosses one; the
+token shift is zero at a segment start and on padding.  Padding tokens
+(seg 0) contribute nothing to the state (their k is zeroed).
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
+from repro.configs.rwkv6_3b import MIX_LORA
 from repro.models.params import (
     EMBED, LORA, MLP, ParamDef, RWKV_HEADS,
 )
@@ -28,7 +30,15 @@ from repro.models.layers import layernorm_def
 from repro.sharding.logical import shard
 
 _MIX_TARGETS = ("r", "k", "v", "w", "g")
-RESET_PENALTY = -1e30
+# The WKV's f32 dots at full f32 precision: the TPU's default rounds their
+# operands to bf16 (as in kernels/wkv6.py).
+F32 = jax.lax.Precision.HIGHEST
+# GroupNorm's epsilon after the WKV, as the Finch code sets it: 1e-5 x
+# head_size_divisor^2 with head_size_divisor 8.  At a document's first
+# token a head's WKV output is one scalar times v, and GroupNorm's gradient
+# through that scalar near 0 is 1/sqrt(eps): the larger epsilon keeps it
+# ~8x smaller than the LayerNorms' 1e-5 would.
+GROUP_NORM_EPS = 1e-5 * 8 ** 2
 
 
 def rwkv6_timemix_def(cfg) -> dict:
@@ -41,8 +51,8 @@ def rwkv6_timemix_def(cfg) -> dict:
     }
     for t in _MIX_TARGETS:
         p[f"mu_{t}"] = ParamDef((d,), (None,), init="uniform", scale=0.5)
-        p[f"mixA_{t}"] = ParamDef((d, 32), (EMBED, LORA), init="scaled")
-        p[f"mixB_{t}"] = ParamDef((32, d), (LORA, EMBED), init="zeros")
+        p[f"mixA_{t}"] = ParamDef((d, MIX_LORA), (EMBED, LORA), init="scaled")
+        p[f"mixB_{t}"] = ParamDef((MIX_LORA, d), (LORA, EMBED), init="zeros")
     for t in ("r", "k", "v", "g", "o"):
         p[f"w_{t}"] = ParamDef((d, d), (EMBED, None), init="scaled")
     p["w0"] = ParamDef((d,), (None,), init="uniform", scale=1.0)
@@ -50,7 +60,7 @@ def rwkv6_timemix_def(cfg) -> dict:
     p["loraB_w"] = ParamDef((lo, d), (LORA, EMBED), init="zeros")
     p["u"] = ParamDef((h, cfg.rwkv_head_dim), (RWKV_HEADS, None),
                       init="uniform", scale=0.5)
-    p["out_ln"] = layernorm_def(cfg.rwkv_head_dim)
+    p["out_ln"] = layernorm_def(d)
     return p
 
 
@@ -66,11 +76,19 @@ def rwkv6_channelmix_def(cfg) -> dict:
     }
 
 
-def _token_shift(x: jax.Array, prev: jax.Array | None) -> jax.Array:
-    """x: (b, s, d) -> previous-token stream; prev: (b, 1, d) carried state."""
-    if prev is None:
-        prev = jnp.zeros_like(x[:, :1])
-    return jnp.concatenate([prev, x[:, :-1]], axis=1)
+def _resets(segment_ids: jax.Array) -> jax.Array:
+    """(b, s) bool: True where a document starts (the segment id differs
+    from the previous token's, or t = 0) and on padding (segment 0)."""
+    prev_seg = jnp.pad(segment_ids[:, :-1], ((0, 0), (1, 0)))
+    return (segment_ids != prev_seg) | (segment_ids == 0)
+
+
+def _token_shift(x: jax.Array, reset: jax.Array) -> jax.Array:
+    """x: (b, s, d) -> the previous token's stream, zero where ``reset``
+    (a document's first token, padding), so a packed document never takes
+    in the one before it."""
+    shifted = jnp.pad(x[:, :-1], ((0, 0), (1, 0), (0, 0)))
+    return jnp.where(reset[..., None], jnp.zeros_like(x), shifted)
 
 
 def _ddlerp(p, t: str, x, xs, base_mix):
@@ -80,12 +98,15 @@ def _ddlerp(p, t: str, x, xs, base_mix):
     return x + (xs - x) * mix
 
 
-def _per_head_ln(p, x, eps):
-    """x: (b, s, h, dk) — GroupNorm(heads) equivalent."""
+def _per_head_ln(p, x):
+    """x: (b, s, h, dk) — GroupNorm(heads) with a scale and bias per
+    channel, (h * dk,)."""
     xf = x.astype(jnp.float32)
     mu = jnp.mean(xf, -1, keepdims=True)
     var = jnp.var(xf, -1, keepdims=True)
-    return ((xf - mu) * jax.lax.rsqrt(var + eps) * p["scale"] + p["bias"])
+    shape = x.shape[-2:]
+    return ((xf - mu) * jax.lax.rsqrt(var + GROUP_NORM_EPS)
+            * p["scale"].reshape(shape) + p["bias"].reshape(shape))
 
 
 def _project(p, cfg, x, x_shift):
@@ -148,17 +169,17 @@ def wkv6_chunked(r, k, v, loga, u, *, chunk: int, reset: jax.Array,
         # state (inter-chunk) term: valid only if NO reset in chunk <= t
         q_valid = (R == 0)[:, None, :, None]
         q_exp = jnp.where(q_valid, jnp.exp(jnp.minimum(cwm1, 0.0)), 0.0)
-        o = jnp.einsum("bhti,bhij->bhtj", rb * q_exp, S)
+        o = jnp.einsum("bhti,bhij->bhtj", rb * q_exp, S, precision=F32)
         # intra: A[t,s] = sum_i r[t,i] k[s,i] exp(cwm1_t - cw_s), s < t,
         # valid iff no reset in (s, t]  <=>  R_t == R_s
         expo = cwm1[:, :, :, None] - cw[:, :, None]  # (b, h, t, s, i)
         pair_valid = (R[:, :, None] == R[:, None, :])[:, None, ..., None]
         ex = jnp.where(pair_valid, jnp.exp(jnp.minimum(expo, 0.0)), 0.0)
-        A = jnp.einsum("bhti,bhsi,bhtsi->bhts", rb, kb, ex)
+        A = jnp.einsum("bhti,bhsi,bhtsi->bhts", rb, kb, ex, precision=F32)
         A = A * tri_strict[None, None]
-        o = o + jnp.einsum("bhts,bhsj->bhtj", A, vb)
+        o = o + jnp.einsum("bhts,bhsj->bhtj", A, vb, precision=F32)
         # diagonal bonus term: (r_t . (u * k_t)) v_t
-        diag = jnp.einsum("bhti,hi,bhti->bht", rb, u, kb)
+        diag = jnp.einsum("bhti,hi,bhti->bht", rb, u, kb, precision=F32)
         o = o + diag[..., None] * vb
         # state update: S' = exp(cw_L) S + sum_s exp(cw_L - cw_s) k_s^T v_s
         # carried state survives only a reset-free chunk; kv_s survives
@@ -169,7 +190,7 @@ def wkv6_chunked(r, k, v, loga, u, *, chunk: int, reset: jax.Array,
         k_hat = kb * jnp.where(
             k_valid, jnp.exp(jnp.minimum(cw[:, :, -1:] - cw, 0.0)), 0.0)
         S_new = S * dec_all[..., None] \
-            + jnp.einsum("bhsi,bhsj->bhij", k_hat, vb)
+            + jnp.einsum("bhsi,bhsj->bhij", k_hat, vb, precision=F32)
         return S_new, o
 
     S0 = jnp.zeros((b, h, dk, dk), jnp.float32)
@@ -181,23 +202,32 @@ def wkv6_chunked(r, k, v, loga, u, *, chunk: int, reset: jax.Array,
     return o
 
 
+def wkv_chunks(segment_ids: jax.Array, chunk: int):
+    """(live, total) (row, chunk) pairs of the chunked WKV over a batch's
+    packed rows (b, s), per head and layer: ``wkv6_chunked`` computes every
+    chunk, padding or not.  int32 scalars."""
+    b, s = segment_ids.shape
+    total = jnp.int32(b * (s // min(chunk, s)))
+    return total, total
+
+
 def rwkv6_timemix_train(p, cfg, x, segment_ids, return_state: bool = False):
     """x: (b, s, d).  Full time-mix sublayer (includes its own LN)."""
     b, s, d = x.shape
     h = d // cfg.rwkv_head_dim
     from repro.models.layers import layernorm
     xn = layernorm(p["ln"], x, cfg.norm_eps)
-    xs = _token_shift(xn, None)
+    reset = _resets(segment_ids)
+    xs = _token_shift(xn, reset)
     r, k, v, g, loga = _project(p, cfg, xn, xs)
-    prev_seg = jnp.pad(segment_ids[:, :-1], ((0, 0), (1, 0)))
-    reset = (segment_ids != prev_seg) | (segment_ids == 0)
     k = k * (segment_ids > 0)[..., None, None]      # padding adds no state
     u = p["u"].astype(jnp.float32)
-    o = wkv6_chunked(r, k, v, loga, u, chunk=cfg.rwkv_chunk, reset=reset,
-                     return_state=return_state)
+    with jax.named_scope("wkv"):
+        o = wkv6_chunked(r, k, v, loga, u, chunk=cfg.rwkv_chunk, reset=reset,
+                         return_state=return_state)
     if return_state:
         o, S_final = o
-    o = _per_head_ln(p["out_ln"], o, cfg.norm_eps) * g.reshape(b, s, h, -1)
+    o = _per_head_ln(p["out_ln"], o) * g.reshape(b, s, h, -1)
     o = shard(o.astype(x.dtype), "batch", "seq", "act_heads", None)
     out = o.reshape(b, s, d) @ p["w_o"]
     if return_state:
@@ -205,10 +235,10 @@ def rwkv6_timemix_train(p, cfg, x, segment_ids, return_state: bool = False):
     return out
 
 
-def rwkv6_channelmix_train(p, cfg, x):
+def rwkv6_channelmix_train(p, cfg, x, segment_ids):
     from repro.models.layers import layernorm
     xn = layernorm(p["ln"], x, cfg.norm_eps)
-    xs = _token_shift(xn, None)
+    xs = _token_shift(xn, _resets(segment_ids))
     xk = xn + (xs - xn) * p["mu_k"].astype(xn.dtype)
     xr = xn + (xs - xn) * p["mu_r"].astype(xn.dtype)
     kk = jnp.square(jax.nn.relu(xk @ p["w_k"]))
@@ -239,10 +269,11 @@ def rwkv6_timemix_decode(p, cfg, x, state):
     u = p["u"].astype(jnp.float32)
     S = state["wkv"]
     r1, k1, v1 = r[:, 0], k[:, 0], v[:, 0]          # (b, h, dk)
-    kv = jnp.einsum("bhi,bhj->bhij", k1, v1)
-    o = jnp.einsum("bhi,bhij->bhj", r1, S + u[None, :, :, None] * kv)
+    kv = jnp.einsum("bhi,bhj->bhij", k1, v1, precision=F32)
+    o = jnp.einsum("bhi,bhij->bhj", r1, S + u[None, :, :, None] * kv,
+                   precision=F32)
     S_new = S * jnp.exp(loga[:, 0])[..., None] + kv
-    o = _per_head_ln(p["out_ln"], o[:, None], cfg.norm_eps)[:, 0] \
+    o = _per_head_ln(p["out_ln"], o[:, None])[:, 0] \
         * g.reshape(b, 1, h, -1)[:, 0]
     out = (o.reshape(b, 1 * d)[:, None, :]).astype(x.dtype) @ p["w_o"]
     return out, {"tm_shift": xn, "wkv": S_new}

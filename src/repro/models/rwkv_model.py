@@ -32,19 +32,26 @@ def forward(params, cfg: ModelConfig, batch, return_state: bool = False):
     h = L.layernorm(params["ln0"], h, cfg.norm_eps)
     h = shard(h, "batch", "seq", "act_embed")
 
+    # The named scopes tag the block's HLO ops (op_name metadata), so a
+    # device trace can group its time by time mix (and its WKV scan) and
+    # channel mix.
     def layer_fn(carry, lp):
         h = carry
         if return_state:
-            tm_out, st = rwkv.rwkv6_timemix_train(lp["tm"], cfg, h, seg,
-                                                  return_state=True)
+            with jax.named_scope("timemix"):
+                tm_out, st = rwkv.rwkv6_timemix_train(
+                    lp["tm"], cfg, h, seg, return_state=True)
             h = h + tm_out
             from repro.models.layers import layernorm
             cm_shift = layernorm(lp["cm"]["ln"], h, cfg.norm_eps)[:, -1:]
-            h = h + rwkv.rwkv6_channelmix_train(lp["cm"], cfg, h)
+            with jax.named_scope("channelmix"):
+                h = h + rwkv.rwkv6_channelmix_train(lp["cm"], cfg, h, seg)
             st["cm_shift"] = cm_shift
             return h, st
-        h = h + rwkv.rwkv6_timemix_train(lp["tm"], cfg, h, seg)
-        h = h + rwkv.rwkv6_channelmix_train(lp["cm"], cfg, h)
+        with jax.named_scope("timemix"):
+            h = h + rwkv.rwkv6_timemix_train(lp["tm"], cfg, h, seg)
+        with jax.named_scope("channelmix"):
+            h = h + rwkv.rwkv6_channelmix_train(lp["cm"], cfg, h, seg)
         h = shard(h, "batch", "seq", "act_embed")
         return h, None
 

@@ -11,11 +11,17 @@ from repro.configs.base import ModelConfig
 from repro.models import params as pdefs
 from repro.models.attention import attention_tiles
 from repro.models.model_zoo import Model
+from repro.models.rwkv import wkv_chunks
 from repro.train.optimizer import (
     AdamWConfig, AdamWState, adamw_update, init_adamw,
 )
 
 AUX_LOSS_WEIGHT = 0.01
+# The step's work counters for its token mixing, (live, total) pairs:
+# attention tiles, or the ssm family's WKV chunks.  Trainer keeps them per
+# step in its history.
+STEP_COUNTERS = ("attn_tiles_live", "attn_tiles_total",
+                 "wkv_chunks_live", "wkv_chunks_total")
 
 
 class TrainState(NamedTuple):
@@ -69,7 +75,10 @@ def make_loss_fn(model: Model):
             total = loss + AUX_LOSS_WEIGHT * aux
         metrics = {"loss": loss, "aux_loss": aux, "accuracy": acc,
                    "tokens": jnp.sum(mask)}
-        if model.cfg.family != "ssm":      # the ssm family has no attention
+        if model.cfg.family == "ssm":      # attention-free: a WKV scan
+            metrics["wkv_chunks_live"], metrics["wkv_chunks_total"] = \
+                wkv_chunks(batch["segment_ids"], model.cfg.rwkv_chunk)
+        else:
             metrics["attn_tiles_live"], metrics["attn_tiles_total"] = \
                 attention_tiles(batch["segment_ids"])
         return total, metrics

@@ -18,7 +18,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.core.orchestrator import Overlord
 from repro.models.model_zoo import Model
 from repro.train.optimizer import AdamWConfig
-from repro.train.train_step import init_train_state, make_train_step
+from repro.train.train_step import (
+    STEP_COUNTERS, init_train_state, make_train_step,
+)
 
 
 @dataclasses.dataclass
@@ -160,9 +162,8 @@ class Trainer:
                "accuracy": m["accuracy"], "grad_norm": m["grad_norm"],
                "tokens": m["tokens"], "host_tokens": m["host_tokens"],
                "fetch_s": fetch_s, "step_s": m["step_s"]}
-        # live and total attention tiles of the step (models with attention)
-        rec.update({k: m[k] for k in ("attn_tiles_live", "attn_tiles_total")
-                    if k in m})
+        # the step's live and total attention tiles or WKV chunks
+        rec.update({k: m[k] for k in STEP_COUNTERS if k in m})
         self.history.append(rec)
         self.ov.step_done(step, {"loss": rec["loss"]})
         if step % self.cfg.log_every == 0:

@@ -18,10 +18,12 @@ ROOT = pathlib.Path(__file__).resolve().parents[1]
 
 @pytest.fixture
 def cache_config():
-    """Restore JAX's compile-cache directory after the test."""
+    """Restore JAX's compile-cache settings after the test."""
     was = jax.config.jax_compilation_cache_dir
+    min_s = jax.config.jax_persistent_cache_min_compile_time_secs
     yield
     jax.config.update("jax_compilation_cache_dir", was)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", min_s)
     compilation_cache.reset_cache()
 
 
@@ -40,6 +42,21 @@ def test_launch_train_two_steps_reduced(cache_config):
         assert h["fetch_s"] >= 0 and h["step_s"] > 0
 
 
+def test_launch_train_rwkv6_keeps_wkv_chunk_counts(cache_config):
+    """The ssm family's step counts its WKV chunks and the Trainer keeps
+    them: 2 DP ranks x 1 row of 64 tokens in chunks of 16 (reduced()) are
+    2 x 4 (row, chunk) pairs a step, every one computed on the jnp scan."""
+    args = train.build_parser().parse_args(
+        ["--arch", "rwkv6-3b", "--reduced", "--steps", "2", "--seq-len",
+         "64", "--dp", "2", "--rows", "1"])
+    out = train.run(args)
+    assert out["config"].rwkv_chunk == 16
+    for h in out["history"]:
+        assert h["wkv_chunks_live"] == h["wkv_chunks_total"] == 2 * 64 / 16
+        assert "attn_tiles_total" not in h
+        assert math.isfinite(h["loss"]) and h["tokens"] == h["host_tokens"]
+
+
 def test_compile_cache_honours_env(monkeypatch, tmp_path, cache_config):
     before = jax.config.jax_compilation_cache_dir
     monkeypatch.setenv(cache.ENV, str(tmp_path))
@@ -53,6 +70,20 @@ def test_compile_cache_defaults_to_checkout(monkeypatch, cache_config):
     path = cache.enable_compile_cache()
     assert path == str(ROOT / ".jax_cache")
     assert jax.config.jax_compilation_cache_dir == path
+
+
+@pytest.mark.parametrize("env", [True, False])
+def test_compile_cache_keeps_every_compile(monkeypatch, tmp_path,
+                                           cache_config, env):
+    """A compile far under JAX's default threshold of 1 s is kept too, so
+    a later run compiles nothing that an earlier one compiled."""
+    if env:
+        monkeypatch.setenv(cache.ENV, str(tmp_path))
+    else:
+        monkeypatch.delenv(cache.ENV, raising=False)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    cache.enable_compile_cache()
+    assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
 
 
 def _python(code_or_script, *, devices=1):

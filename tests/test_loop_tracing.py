@@ -168,3 +168,15 @@ def test_lowered_step_names_its_blocks():
     # "jit(train_step)/jvp(loss)/ge" or ".../rematted_computation/ffn/mul"
     for scope in ("attention", "ffn", "loss", "optimizer"):
         assert re.search(rf'loc\("[^"]*[/(]{scope}[/)]', text), scope
+
+
+def test_lowered_rwkv6_step_names_its_blocks():
+    from repro.configs.rwkv6_3b import reduced
+    model = build_model(reduced())
+    b, s = 2, 64
+    batch = {k: jax.ShapeDtypeStruct((b, s), np.int32)
+             for k in ("tokens", "segment_ids", "positions", "labels")}
+    text = jax.jit(make_train_step(model)).lower(
+        abstract_train_state(model), batch).as_text(debug_info=True)
+    for scope in ("timemix", "wkv", "channelmix", "loss", "optimizer"):
+        assert re.search(rf'loc\("[^"]*[/(]{scope}[/)]', text), scope

@@ -9,7 +9,7 @@ from repro.core import (
     ClientPlaceTree, Overlord, OverlordConfig, StaticSchedule,
 )
 from repro.core.colocated import ColocatedFleet
-from repro.data.cost_models import backbone_cost, encoder_cost
+from repro.data.cost_models import active_params, backbone_cost, encoder_cost
 from repro.data.sources import coyo_like_specs, materialize_group
 
 
@@ -162,3 +162,18 @@ def test_curriculum_mixture_shifts(source_paths):
     w0 = sched.weights(0)
     w8 = sched.weights(8)
     assert w0["coyo_000"] > 0.9 and w8["coyo_003"] > 0.9
+
+
+def test_rwkv6_cost_counts_its_own_block():
+    """Published RWKV6-3B, per layer: the time mix's r, k, v, g and output
+    matrices (5 d^2 = 32,768,000), its five token-shift LoRAs (5 x 2 x d x
+    32 = 819,200) and its decay LoRA (2 x d x 64 = 327,680); the channel
+    mix's key and value (2 d d_ff = 45,875,200) and receptance (d^2 =
+    6,553,600).  The embedding and the head are not counted.  No attention,
+    so the planner's cost is linear in tokens."""
+    cfg = get_config("rwkv6-3b")
+    per_layer = 86_343_680
+    assert active_params(cfg) == 32 * per_layer
+    cost = backbone_cost(cfg)
+    assert cost.name == "linear"
+    assert cost({"text_tokens": 100}) == 6.0 * 32 * per_layer * 100

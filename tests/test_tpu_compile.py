@@ -1,12 +1,12 @@
 """Compiles for a described TPU v5e, with no chip attached.
 
 The Pallas kernels at the widths ``chip_smoke.py`` runs them must lower to
-Mosaic (``tpu_custom_call``; packed attention forward and backward), and
-the donated qwen3-8b ``chip_share()`` train step must carry the attention
-kernel and fit one v5e's 16 GiB; the 4-device data-parallel step must
-compile with its gradient all-reduce and run the kernel on each device's
-own rows, gathering no q, k or v.  Nothing runs: these catch what the
-chip's compiler would refuse, at no chip time.
+Mosaic (``tpu_custom_call``; packed attention forward and backward), the
+donated qwen3-8b ``chip_share()`` train step must carry the attention
+kernel and fit one v5e's 16 GiB, as must the rwkv6-3b cell's step; the
+4-device data-parallel step must compile with its gradient all-reduce and
+run the kernel on each device's own rows, gathering no q, k or v.  Nothing
+runs: these catch what the chip's compiler would refuse, at no chip time.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library.
@@ -79,13 +79,13 @@ def test_kernel_lowers_to_mosaic(topo, name):
     assert "tpu_custom_call" in exe.as_text()
 
 
-def _compile_chip_share_step(devices, rows, seq_len):
+def _compile_chip_share_step(devices, rows, seq_len, cfg=None):
     from repro.configs.qwen3_8b import chip_share
     from repro.models.model_zoo import build_model
     from repro.train.optimizer import AdamWConfig
     from repro.train.train_step import abstract_train_state
     from repro.train.trainer import data_parallel_step
-    model = build_model(chip_share())
+    model = build_model(cfg or chip_share())
     mesh = Mesh(np.array(devices), ("data",))
     batch = {k: jax.ShapeDtypeStruct((rows, seq_len), jnp.int32)
              for k in ("tokens", "segment_ids", "positions", "labels")}
@@ -105,6 +105,17 @@ def test_chip_share_train_step_fits_one_v5e(topo):
     exe = _compile_chip_share_step(topo.devices[:1], 2, 4096)
     assert exe.memory_analysis().alias_size_in_bytes > 0   # state donated
     assert "tpu_custom_call" in exe.as_text()
+    assert _peak_bytes(exe) < 0.9 * V5E_HBM_BYTES
+
+
+def test_rwkv6_train_step_fits_one_v5e(topo):
+    """The rwkv6-3b cell's step: published widths, 4 layers, 1/8 of the
+    vocabulary, 2 DP rows x 4096 tokens on one chip, its WKV the chunked
+    jnp scan (compiled peak 9.92e9 bytes)."""
+    from repro.configs import get_config
+    cfg = get_config("rwkv6-3b").replace(num_layers=4, vocab_size=8192)
+    exe = _compile_chip_share_step(topo.devices[:1], 2, 4096, cfg)
+    assert exe.memory_analysis().alias_size_in_bytes > 0   # state donated
     assert _peak_bytes(exe) < 0.9 * V5E_HBM_BYTES
 
 
