@@ -19,10 +19,6 @@ from repro.core.placetree import ClientPlaceTree
 from repro.core.resilience import validate_positive_policy
 from repro.core.strategies import STRATEGIES
 
-# mean tokens/sample the orchestrator uses when auto-sizing a step
-# (Overlord.start keeps the same constant)
-EST_TOKENS_PER_SAMPLE = 96
-
 _KNOWN_FAMILIES = {"dense", "moe", "hybrid", "vlm", "audio", "ssm"}
 _KNOWN_DTYPES = {"bfloat16", "float32", "float16"}
 _KNOWN_REMAT = {"none", "layer", "dots_saveable"}
@@ -198,37 +194,58 @@ def _lint_against_tree(cfg: OverlordConfig, tree: ClientPlaceTree,
 
     # CFG306 — step sizing vs packing capacity (bucket count vs DP degree)
     nb = tree.buckets(axis)
-    capacity_tokens = nb * cfg.n_bins * cfg.rows_per_microbatch \
-        * cfg.seq_len
-    sps = cfg.samples_per_step or max(
-        nb * cfg.n_bins,
-        int(capacity_tokens * cfg.fill_factor / EST_TOKENS_PER_SAMPLE))
-    if sps < nb * cfg.n_bins:
+    bins = nb * cfg.n_bins
+    capacity_tokens = cfg.capacity_tokens(tree)
+    sps = cfg.samples_per_step
+    if sps == 0:
+        budget = cfg.budget_tokens(tree)
+        if budget < bins:
+            rep.add("CFG306", Severity.ERROR,
+                    f"token budget {budget} (fill_factor "
+                    f"{cfg.fill_factor} of {capacity_tokens}) cannot "
+                    f"populate {nb} bucket(s) x {cfg.n_bins} bin(s)",
+                    where,
+                    "every microbatch bin needs at least one sample of "
+                    "at least one token; raise fill_factor")
+    elif sps < bins:
         rep.add("CFG306", Severity.ERROR,
                 f"samples_per_step={sps} cannot populate "
                 f"{nb} bucket(s) x {cfg.n_bins} bin(s)", where,
                 "every microbatch bin needs at least one sample or the "
                 "train step receives an empty packed batch")
-    elif sps * EST_TOKENS_PER_SAMPLE > capacity_tokens:
+    elif sps > capacity_tokens:
         rep.add("CFG306", Severity.WARNING,
-                f"samples_per_step={sps} (~{sps * EST_TOKENS_PER_SAMPLE} "
-                f"tokens) exceeds packing capacity {capacity_tokens} "
-                f"tokens ({nb} buckets x {cfg.n_bins} bins x "
-                f"{cfg.rows_per_microbatch} rows x {cfg.seq_len})", where,
-                "overflow samples are silently dropped by the packer; "
-                "lower samples_per_step or raise rows_per_microbatch")
+                f"samples_per_step={sps} exceeds packing capacity "
+                f"{capacity_tokens} tokens ({nb} buckets x {cfg.n_bins} "
+                f"bins x {cfg.rows_per_microbatch} rows x {cfg.seq_len}) "
+                f"even at one token a sample", where,
+                "overflow samples are dropped by the packer; set "
+                "samples_per_step=0 to draw to a token budget instead")
 
     # CFG307 — prefetch pressure vs loader buffer depth
-    if n_sources:
-        per_source_demand = -(-sps // n_sources)  # ceil
-        if cfg.buffer_target < per_source_demand:
+    if not n_sources:
+        return
+    if sps == 0:
+        # a buffer of buffer_target samples holds at most
+        # buffer_target * seq_len packed tokens
+        share = -(-cfg.budget_tokens(tree) // n_sources)  # ceil
+        if cfg.buffer_target * cfg.seq_len < share:
             rep.add("CFG307", Severity.WARNING,
-                    f"buffer_target={cfg.buffer_target} < ~"
-                    f"{per_source_demand} samples a single step draws "
-                    f"per source ({n_sources} sources, prefetch="
-                    f"{cfg.prefetch})", where,
+                    f"buffer_target={cfg.buffer_target} samples of at most "
+                    f"{cfg.seq_len} tokens cannot hold the ~{share} tokens "
+                    f"a single step draws per source ({n_sources} "
+                    f"sources)", where,
                     "a skewed mix() can drain a loader buffer mid-step "
                     "and stall prefetch; raise buffer_target")
+        return
+    demand = -(-sps // n_sources)  # ceil
+    if cfg.buffer_target < demand:
+        rep.add("CFG307", Severity.WARNING,
+                f"buffer_target={cfg.buffer_target} < ~{demand} samples a "
+                f"single step draws per source ({n_sources} sources, "
+                f"prefetch={cfg.prefetch})", where,
+                "a skewed mix() can drain a loader buffer mid-step and "
+                "stall prefetch; raise buffer_target")
 
 
 # ------------------------------------------------------------------ model

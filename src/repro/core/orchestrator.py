@@ -41,7 +41,7 @@ class OverlordConfig:
     seq_len: int = 512
     rows_per_microbatch: int = 4      # packed rows per bucket per bin
     n_bins: int = 2                   # microbatches per bucket
-    samples_per_step: int = 0         # 0 -> auto from capacity
+    samples_per_step: int = 0         # 0 -> draw to the token budget
     strategy: str = "backbone_balance"
     strategy_params: dict = dataclasses.field(default_factory=dict)
     prefetch: int = 2
@@ -71,7 +71,7 @@ class OverlordConfig:
     keep_epochs: int = 3
     vocab_size: int = 50_000
     seed: int = 0
-    fill_factor: float = 0.6          # packing headroom
+    fill_factor: float = 0.6          # packed-row occupancy target
     # resilience (docs/FAULT_TOLERANCE.md; validated by CFG309)
     retry: RetryPolicy = dataclasses.field(default_factory=RetryPolicy)
     breaker_failures: int = 3         # consecutive read failures -> open
@@ -81,6 +81,16 @@ class OverlordConfig:
     # unified telemetry plane (docs/TELEMETRY.md)
     telemetry: bool = True            # metrics + trace spans
     telemetry_max_spans: int = 65536  # bounded span retention
+
+    def capacity_tokens(self, tree: ClientPlaceTree) -> int:
+        """Packed-row slots of one step: buckets x bins x rows x seq_len."""
+        nb = tree.buckets(self.strategy_params.get("axis", "DP"))
+        return nb * self.n_bins * self.rows_per_microbatch * self.seq_len
+
+    def budget_tokens(self, tree: ClientPlaceTree) -> int:
+        """Packed tokens an auto-sized step (``samples_per_step`` 0) draws:
+        ``fill_factor`` of the capacity, so never more than it."""
+        return int(self.fill_factor * self.capacity_tokens(tree))
 
 
 class Overlord:
@@ -155,15 +165,6 @@ class Overlord:
             # claim the job fence FIRST: from here on, any zombie
             # incarnation of a previous process is locked out of commits
             self.store.acquire_fence()
-        if cfg.samples_per_step == 0:
-            nb = self.tree.buckets(
-                cfg.strategy_params.get("axis", "DP"))
-            capacity = nb * cfg.n_bins * cfg.rows_per_microbatch \
-                * cfg.seq_len
-            # rough mean tokens/sample for sizing
-            cfg.samples_per_step = max(
-                nb * cfg.n_bins,
-                int(capacity * cfg.fill_factor / 96))
 
         # loaders (phase-1 auto-partitioning)
         if cfg.auto_partition:
@@ -198,7 +199,9 @@ class Overlord:
         self._planner_args = dict(
             tree=self.tree, schedule=self.schedule, strategy=strategy,
             strategy_params=sparams,
-            samples_per_step=cfg.samples_per_step, seed=cfg.seed,
+            samples_per_step=cfg.samples_per_step,
+            budget_tokens=cfg.budget_tokens(self.tree),
+            seq_len=cfg.seq_len, rows=cfg.rows_per_microbatch, seed=cfg.seed,
             ledger=self.ledger, telemetry=self.telemetry,
             plan_ahead=cfg.plan_ahead, fanout=cfg.fanout_rpc)
         self.planner = self.runtime.spawn(

@@ -19,6 +19,7 @@ from repro.core.mixing import MixSchedule
 from repro.core.placetree import ClientPlaceTree
 from repro.core.primitives import LoadingPlan, Orchestration
 from repro.core.resilience import RetryPolicy
+from repro.data.packing import first_fit, packed_length
 from repro.telemetry import Telemetry, ensure_telemetry
 
 
@@ -39,12 +40,24 @@ class _HealthyRemix(MixSchedule):
         self.base.observe(step, metrics)
 
 
+def _by_loader(plan: LoadingPlan, owner: dict) -> dict[str, list]:
+    """The plan's entries by owning loader, loaders in order of first
+    appearance."""
+    out: dict[str, list] = collections.defaultdict(list)
+    for e in plan.entries:
+        ln = owner.get(e.sample_id)
+        if ln is not None:
+            out[ln].append(e)
+    return out
+
+
 class Planner(Actor):
     def __init__(self, tree: ClientPlaceTree, schedule: MixSchedule,
                  strategy: Callable, strategy_params: dict,
                  loaders: dict[str, ActorHandle],
                  constructors: dict[int, ActorHandle],
                  samples_per_step: int, seed: int = 0,
+                 budget_tokens: int = 0, seq_len: int = 0, rows: int = 1,
                  scale_threshold: float = 1.5,
                  scale_patience: int = 3,
                  ledger=None,
@@ -58,7 +71,13 @@ class Planner(Actor):
         self.strategy_params = dict(strategy_params)
         self.loaders = dict(loaders)          # name -> handle
         self.constructors = dict(constructors)
+        # samples_per_step 0: draw budget_tokens packed tokens a step, and
+        # defer to a later step what the packer's first-fit over seq_len x
+        # rows would not place
         self.samples_per_step = samples_per_step
+        self.budget_tokens = budget_tokens
+        self.seq_len = seq_len
+        self.rows = rows
         self.seed = seed
         self._planned_through = -1
         self._history: collections.OrderedDict = collections.OrderedDict()
@@ -180,39 +199,87 @@ class Planner(Actor):
                                            "strategy")):
                 # selection + costing + bucketing/binning all run inside
                 # the strategy over a fresh Orchestration
-                ctx = Orchestration(buffer_meta, self.tree, step, self.seed)
+                ctx = Orchestration(buffer_meta, self.tree, step, self.seed,
+                                    budget_tokens=self.budget_tokens,
+                                    seq_len=self.seq_len)
                 plan: LoadingPlan = self.strategy(
                     ctx, schedule=schedule, total=self.samples_per_step,
                     **self.strategy_params)
-            plan_dispatched = self._dispatch(step, plan, owner)
+            order = self._pack_order(plan, owner)
+            if self.samples_per_step == 0:
+                deferred = self._defer_overflow(plan, order, buffer_meta)
+                plan_sp.set_attr("budget_tokens", self.budget_tokens)
+                plan_sp.set_attr("drawn_tokens",
+                                 plan.diagnostics.get("drawn_tokens", 0))
+                plan_sp.set_attr("deferred", deferred)
+                tel.inc("planner_deferred_total", deferred)
+            plan_dispatched = self._dispatch(step, plan, owner, order)
         tel.observe("planner_plan_seconds", plan_sp.duration)
         self._record_plan_metrics(plan)
         return plan_dispatched
 
-    def _dispatch(self, step: int, plan: LoadingPlan, owner: dict):
+    @staticmethod
+    def _pack_order(plan: LoadingPlan, owner: dict) -> dict:
+        """bucket -> [(loader, entry)] in the order its constructor packs
+        them: grouped by source in order of first appearance, loaders in
+        order of first appearance in the plan, each loader's entries in
+        plan order (what ``_ingest_wave`` and ``DataConstructor.ingest``
+        make of the deposits)."""
+        per: dict = collections.defaultdict(
+            lambda: collections.defaultdict(list))
+        for ln, entries in _by_loader(plan, owner).items():
+            for e in entries:
+                per[e.bucket][e.source].append((ln, e))
+        return {b: [x for xs in srcs.values() for x in xs]
+                for b, srcs in per.items()}
+
+    def _defer_overflow(self, plan: LoadingPlan, order: dict,
+                        buffer_meta: list[dict]) -> int:
+        """Run the packer's first-fit over each (bucket, bin)'s metadata
+        lengths in pack order, and take what it would not place out of
+        the plan (and ``order``): it stays in its loader's buffer for a
+        later step instead of being dropped.  Placements of the rest do
+        not change, since first-fit skips what does not fit.  Returns
+        how many samples were deferred."""
+        length = {m["sample_id"]: packed_length(m["text_tokens"],
+                                                self.seq_len)
+                  for m in buffer_meta}
+        out: set[int] = set()
+        for b, pairs in order.items():
+            for k in range(plan.bins):
+                entries = [e for _, e in pairs if e.bin == k]
+                lens = [length[e.sample_id] for e in entries]
+                for e, n, row in zip(entries, lens,
+                                     first_fit(lens, self.seq_len,
+                                               self.rows)):
+                    if row is None and n > 0:
+                        out.add(id(e))
+            order[b] = [(ln, e) for ln, e in pairs if id(e) not in out]
+        if out:
+            plan.entries = [e for e in plan.entries if id(e) not in out]
+        return len(out)
+
+    def _dispatch(self, step: int, plan: LoadingPlan, owner: dict,
+                  order: dict):
         tel = self.telemetry
         # direct loaders: prepare planned samples (transform on the loader),
         # THEN announce realized counts + deposit, so a loader failing
         # mid-plan can never wedge a constructor on missing counts.
-        by_loader: dict[str, list] = collections.defaultdict(list)
-        for e in plan.entries:
-            ln = owner.get(e.sample_id)
-            if ln is not None:
-                by_loader[ln].append(e)
+        by_loader = _by_loader(plan, owner)
         with tel.span("planner.dispatch", step=step):
             # stage 1 — prepare: one overlapped wave across loaders, so
             # the transform cost is the max over loaders, not the sum
             prepared = self._prepare_wave(by_loader)
+            by_id = {ln: {s.sample_id: s for s in samples}
+                     for ln, samples in prepared.items()
+                     if samples is not None}
             deposits = collections.defaultdict(list)  # bkt -> [(src,s,bin)]
-            for lname, entries in by_loader.items():
-                samples = prepared.get(lname)
-                if samples is None:
-                    continue  # supervision promotes a shadow; degrade
-                by_id = {s.sample_id: s for s in samples}
-                for e in entries:
-                    if e.sample_id in by_id:
-                        deposits[e.bucket].append(
-                            (e.source, by_id[e.sample_id], e.bin))
+            for bucket, pairs in order.items():
+                for ln, e in pairs:
+                    # absent: supervision promotes a shadow; degrade
+                    s = by_id.get(ln, {}).get(e.sample_id)
+                    if s is not None:
+                        deposits[bucket].append((e.source, s, e.bin))
             # stage 2 — batched ingest: expect+deposit collapsed into one
             # RPC per constructor, fanned out as a second wave
             accepted = self._ingest_wave(step, plan, deposits)

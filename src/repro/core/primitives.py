@@ -18,6 +18,7 @@ from repro.core.balance import balance_items, bin_loads, imbalance
 from repro.core.dgraph import DGraph, SELECTED
 from repro.core.mixing import MixSchedule, sample_counts
 from repro.core.placetree import ClientPlaceTree
+from repro.data.packing import packed_length
 
 
 @dataclasses.dataclass
@@ -52,11 +53,18 @@ class LoadingPlan:
 
 
 class Orchestration:
+    """``budget_tokens`` and ``seq_len`` size a step that ``mix`` is asked
+    for no sample count (``total`` 0): it then draws packed tokens up to
+    the budget instead."""
+
     def __init__(self, buffer_meta: Sequence[dict], tree: ClientPlaceTree,
-                 step: int, seed: int = 0):
+                 step: int, seed: int = 0, budget_tokens: int = 0,
+                 seq_len: int = 0):
         self.buffer_meta = list(buffer_meta)
         self.tree = tree
         self.step = step
+        self.budget_tokens = budget_tokens
+        self.seq_len = seq_len
         self.rng = np.random.default_rng(hash((seed, step)) % 2**32)
         self._selected: list[dict] = []
         self._graphs: dict[str, DGraph] = {}
@@ -70,23 +78,63 @@ class Orchestration:
     # ---------------------------------------------------------- mix()
     def mix(self, schedule: MixSchedule, total_samples: int) -> list[dict]:
         """Probabilistic source selection for this step.  Only sampled data
-        participates in subsequent orchestration."""
+        participates in subsequent orchestration.  ``total_samples`` > 0
+        draws that many samples; 0 draws to the token budget."""
         weights = schedule.weights(self.step)
         by_source: dict[str, list[dict]] = {}
         for m in self.buffer_meta:
             by_source.setdefault(m["source"], []).append(m)
-        counts = sample_counts(
-            {s: w for s, w in weights.items() if s in by_source},
-            total_samples, self.rng)
-        picked = []
-        for src, k in counts.items():
-            avail = by_source.get(src, [])
-            picked.extend(avail[:k])   # FIFO from the loader buffer
+        live = {s: w for s, w in weights.items() if s in by_source}
+        if total_samples > 0:
+            counts = sample_counts(live, total_samples, self.rng)
+            picked = []
+            for src, k in counts.items():
+                avail = by_source.get(src, [])
+                picked.extend(avail[:k])   # FIFO from the loader buffer
+        else:
+            picked = self._draw_to_budget(live, by_source)
         self._selected = picked
         self._diag["mix_weights"] = weights
         self._diag["mix_counts"] = {s: len([m for m in picked
                                             if m["source"] == s])
-                                    for s in counts}
+                                    for s in sorted(live)}
+        return picked
+
+    def _draw_to_budget(self, weights: dict[str, float],
+                        by_source: dict[str, list[dict]]) -> list[dict]:
+        """Draw sources one at a time by ``weights`` from the step's rng,
+        each source's next buffered sample FIFO, until the next sample's
+        packed tokens would pass the budget.  A source whose buffer runs
+        dry is skipped (its draws are rejected), so the rest keep their
+        relative weights."""
+        names = sorted(n for n, v in weights.items() if v > 0)
+        probs = np.array([weights[n] for n in names], float)
+        probs /= probs.sum()
+
+        def draws():
+            while True:
+                yield from self.rng.choice(len(names), size=64, p=probs)
+
+        taken = dict.fromkeys(names, 0)
+        dry: set[str] = set()
+        picked: list[dict] = []
+        drawn = 0
+        for i in draws() if names else ():
+            src = names[i]
+            if taken[src] == len(by_source[src]):
+                dry.add(src)
+                if len(dry) == len(names):
+                    break
+                continue
+            m = by_source[src][taken[src]]
+            n = packed_length(m["text_tokens"], self.seq_len)
+            if drawn + n > self.budget_tokens:
+                break
+            picked.append(m)
+            taken[src] += 1
+            drawn += n
+        self._diag["budget_tokens"] = self.budget_tokens
+        self._diag["drawn_tokens"] = drawn
         return picked
 
     # -------------------------------------------------------- dgraph()

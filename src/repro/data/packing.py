@@ -36,12 +36,35 @@ class PackedBatch:
         return out
 
 
+def packed_length(n_text: int, seq_len: int) -> int:
+    """Tokens a sample occupies in a packed row: its text tokens,
+    truncated to ``seq_len`` (image tokens are not packed).  The planner
+    sizes steps and predicts the packer's placements from this."""
+    return min(int(n_text), seq_len)
+
+
+def first_fit(lengths: Sequence[int], seq_len: int,
+              rows: int) -> list[Optional[int]]:
+    """The packer's placement: each length (already ``packed_length``) in
+    order goes to the first of ``rows`` rows with room, else ``None``.
+    An empty sample is never placed."""
+    fill = [0] * rows
+    out: list[Optional[int]] = []
+    for n in lengths:
+        row = next((r for r in range(rows) if fill[r] + n <= seq_len),
+                   None) if n > 0 else None
+        if row is not None:
+            fill[row] += n
+        out.append(row)
+    return out
+
+
 def pack_sequences(samples: Sequence, seq_len: int, rows: int,
                    pad_id: int = 0) -> PackedBatch:
     """First-fit packing of ``samples`` (each with .tokens) into a fixed
     (rows, seq_len) buffer.  Oversized samples are truncated to seq_len.
-    Samples that don't fit are dropped (the planner sizes the selection so
-    this doesn't happen in practice; tests assert on it)."""
+    Samples that don't fit are dropped (in token-budget mode the planner
+    defers them to a later step instead, so none reach this point)."""
     tokens = np.full((rows, seq_len), pad_id, np.int32)
     seg = np.zeros((rows, seq_len), np.int32)
     pos = np.zeros((rows, seq_len), np.int32)
@@ -49,15 +72,12 @@ def pack_sequences(samples: Sequence, seq_len: int, rows: int,
     fill = [0] * rows
     nseg = [0] * rows
     doc_ids: list[list[str]] = [[] for _ in range(rows)]
-    for sm in samples:
-        toks = np.asarray(sm.tokens, np.int32)[:seq_len]
-        n = len(toks)
-        if n == 0:
-            continue
-        # first row with room
-        row = next((r for r in range(rows) if fill[r] + n <= seq_len), None)
+    lengths = [packed_length(len(sm.tokens), seq_len) for sm in samples]
+    for sm, n, row in zip(samples, lengths,
+                          first_fit(lengths, seq_len, rows)):
         if row is None:
             continue
+        toks = np.asarray(sm.tokens, np.int32)[:n]
         a = fill[row]
         tokens[row, a:a + n] = toks
         nseg[row] += 1

@@ -232,6 +232,11 @@ def test_config_unknown_axis_needs_tree():           # CFG305
 
 
 def test_config_capacity_overflow_warns():           # CFG306
+    # auto: the token budget is fill_factor of capacity, never over it
+    auto = lint_overlord_config(
+        good_overlord_cfg(samples_per_step=0, fill_factor=1.0, seq_len=128,
+                          rows_per_microbatch=1, n_bins=1), tree=tree4())
+    assert "CFG306" not in rules(auto)
     rep = lint_overlord_config(
         good_overlord_cfg(samples_per_step=10_000, seq_len=128,
                           rows_per_microbatch=1, n_bins=1),
@@ -240,6 +245,16 @@ def test_config_capacity_overflow_warns():           # CFG306
     rep2 = lint_overlord_config(good_overlord_cfg(samples_per_step=2,
                                                   n_bins=2), tree=tree4())
     assert "CFG306" in {f.rule for f in rep2.errors}  # can't fill bins
+    # a budget of 0.6 x 4 x 2 x 1 x 4 = 19 tokens fills 8 bins; 0.2 x 32
+    # = 6 tokens cannot
+    ok = lint_overlord_config(good_overlord_cfg(
+        samples_per_step=0, seq_len=4, rows_per_microbatch=1, n_bins=2),
+        tree=tree4())
+    assert "CFG306" not in rules(ok)
+    rep3 = lint_overlord_config(good_overlord_cfg(
+        samples_per_step=0, fill_factor=0.2, seq_len=4,
+        rows_per_microbatch=1, n_bins=2), tree=tree4())
+    assert "CFG306" in {f.rule for f in rep3.errors}
 
 
 def test_config_buffer_starvation_warns():           # CFG307
@@ -247,6 +262,16 @@ def test_config_buffer_starvation_warns():           # CFG307
         good_overlord_cfg(samples_per_step=512, buffer_target=16),
         tree=tree4(), n_sources=2)
     assert "CFG307" in {f.rule for f in rep.warnings}
+    # auto: 0.6 x 4 x 2 x 4 x 512 = 9830 tokens, 4915 per source; 16
+    # samples hold at most 16 x 512 = 8192 tokens, 8 at most 4096
+    fits = lint_overlord_config(
+        good_overlord_cfg(samples_per_step=0, buffer_target=16),
+        tree=tree4(), n_sources=2)
+    assert "CFG307" not in rules(fits)
+    rep2 = lint_overlord_config(
+        good_overlord_cfg(samples_per_step=0, buffer_target=8),
+        tree=tree4(), n_sources=2)
+    assert "CFG307" in {f.rule for f in rep2.warnings}
 
 
 def test_config_inverted_ckpt_frequencies():         # CFG308
