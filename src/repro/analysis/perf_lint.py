@@ -8,9 +8,9 @@ one overlapped ``call_async`` wave (``FanOut``) pays ~1.
 PERF701 flags exactly that shape in ``core/`` files: a synchronous
 ``.call(...)`` whose receiver is derived from the target of an enclosing
 ``for`` loop (i.e. the handle being iterated).  Loops that are serial on
-purpose — operator introspection, the measured non-pipelined baseline —
-opt out with a ``# perf: serial ok`` comment on the loop header, the
-call line, or the line directly above the call.
+purpose — operator introspection, recovery paths — opt out with a
+``# perf: serial ok`` comment on the loop header, the call line, or the
+line directly above the call.
 
 ``call_async``/``cast`` receivers never match (they do not block), and
 neither does a blocking call on a FIXED handle inside a step loop

@@ -1,10 +1,6 @@
 from repro.configs.base import (  # noqa: F401
     ModelConfig,
-    ShapeConfig,
-    SHAPES,
-    assigned_archs,
     get_config,
     list_configs,
     register,
-    shape_applicable,
 )

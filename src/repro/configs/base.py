@@ -1,9 +1,10 @@
-"""Config system: model configs, input-shape configs, registry.
+"""Config system: model configs and their registry.
 
 Every assigned architecture has one file in this package defining a
 ``CONFIG: ModelConfig`` with the exact published numbers, plus a
-``reduced()`` variant used by CPU smoke tests.  The FULL configs are only
-ever exercised through the dry-run (ShapeDtypeStruct, no allocation).
+``reduced()`` variant used by CPU smoke tests.  The FULL configs are never
+allocated; the chip runs cut them to one chip's share (``chip_share()``,
+``bench/configs``).
 """
 from __future__ import annotations
 
@@ -66,32 +67,6 @@ class ModelConfig:
         return dataclasses.replace(self, **kw)
 
 
-@dataclasses.dataclass(frozen=True)
-class ShapeConfig:
-    name: str
-    kind: str            # train | prefill | decode
-    seq_len: int
-    global_batch: int
-
-
-SHAPES: dict[str, ShapeConfig] = {
-    "train_4k": ShapeConfig("train_4k", "train", 4_096, 256),
-    "prefill_32k": ShapeConfig("prefill_32k", "prefill", 32_768, 32),
-    "decode_32k": ShapeConfig("decode_32k", "decode", 32_768, 128),
-    "long_500k": ShapeConfig("long_500k", "decode", 524_288, 1),
-}
-
-# long_500k needs sub-quadratic attention: only SSM / hybrid families run it
-# (see DESIGN.md §4); everything else records an explicit skip.
-_SUBQUADRATIC_FAMILIES = ("hybrid", "ssm")
-
-
-def shape_applicable(cfg: ModelConfig, shape: ShapeConfig) -> tuple[bool, str]:
-    if shape.name == "long_500k" and cfg.family not in _SUBQUADRATIC_FAMILIES:
-        return False, "long_500k skipped: quadratic full attention (DESIGN.md §4)"
-    return True, ""
-
-
 _REGISTRY: dict[str, ModelConfig] = {}
 
 
@@ -119,11 +94,6 @@ _ASSIGNED = [
     "yi_9b", "qwen3_32b", "zamba2_7b", "pixtral_12b", "whisper_medium",
     "rwkv6_3b",
 ]
-
-
-def assigned_archs() -> list[str]:
-    _ensure_loaded()
-    return [a.replace("_", "-") for a in _ASSIGNED]
 
 
 _LOADED = False

@@ -1,9 +1,10 @@
 """Pixtral-12B  [hf:mistralai/Pixtral-12B-2409] — VLM.
 
 Backbone (mistral-nemo-like): 40L d_model=5120 32H (GQA kv=8) d_ff=14336
-vocab=131072.  The pixtral-ViT frontend is a STUB per the assignment:
-``input_specs()`` provides precomputed patch embeddings at backbone width,
-fused into the token sequence at given positions.
+vocab=131072.  The pixtral-ViT frontend is a STUB per the assignment: the
+forward takes precomputed patch embeddings at backbone width, fused into the
+token sequence at given positions.  No training path produces them yet
+(ROADMAP.md, B1); tests build them in ``tests/conftest.py: make_lm_batch``.
 """
 from repro.configs.base import ModelConfig, register
 

@@ -1,8 +1,10 @@
 """Whisper-medium  [arXiv:2212.04356] — encoder-decoder audio model.
 
 24L (x2: encoder+decoder) d_model=1024 16H (kv=16, MHA) d_ff=4096
-vocab=51865.  The conv frontend is a STUB: ``input_specs()`` provides
-precomputed frame embeddings (1500 frames = 30s audio), per the assignment.
+vocab=51865.  The conv frontend is a STUB, per the assignment: the forward
+takes precomputed frame embeddings (1500 frames = 30s audio).  No training
+path produces them yet (ROADMAP.md, B1); tests build them in
+``tests/conftest.py: make_lm_batch``.
 """
 from repro.configs.base import ModelConfig, register
 

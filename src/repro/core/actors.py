@@ -4,7 +4,7 @@ Semantics kept from the paper's needs: named actors with isolated state and
 a mailbox thread, async ``cast`` / sync ``call`` with futures, abrupt
 ``kill`` (simulated crash: pending mail dropped, no cleanup), heartbeat
 supervision with failure callbacks, and memory introspection for the
-resource benchmarks.  On a real cluster this class is the only thing to
+plane's memory report.  On a real cluster this class is the only thing to
 swap for Ray/K8s actors.
 """
 from __future__ import annotations

@@ -95,14 +95,11 @@ def unframe_blob(data: bytes) -> bytes:
 class CheckpointStore:
     def __init__(self, root: Optional[str] = None,
                  planner_every: int = 1, loader_every: int = 8,
-                 restore_delay_s: float = 0.0, keep_epochs: int = 3):
+                 keep_epochs: int = 3):
         self.root = root
         self.planner_every = planner_every
         self.loader_every = loader_every
         self.keep_epochs = max(int(keep_epochs), 1)
-        # models remote persistent-store read latency (benchmarks inject a
-        # realistic value; production would see storage RTT here)
-        self.restore_delay_s = restore_delay_s
         self._mem: dict[str, tuple[int, bytes]] = {}
         self._lock = threading.Lock()
         self._saves: collections.Counter = collections.Counter()
@@ -482,8 +479,6 @@ class CheckpointStore:
         consistent on-disk manifest, then orphan/legacy ``.ckpt`` files.
         Corrupt data is counted in ``stats()['load_failures']`` and
         falls back instead of raising."""
-        if self.restore_delay_s:
-            time.sleep(self.restore_delay_s)
         with self._lock:
             if name in self._mem:
                 blob = self._mem[name][1]
@@ -528,8 +523,6 @@ class CheckpointStore:
         entry = (manifest or {}).get("actors", {}).get(name)
         if entry is None:
             return None
-        if self.restore_delay_s:   # simulated persistent-store read RTT
-            time.sleep(self.restore_delay_s)
         return self._load_entry(name, entry)
 
     def load_ledger(self, manifest: dict) -> Optional[object]:

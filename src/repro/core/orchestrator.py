@@ -47,12 +47,9 @@ class OverlordConfig:
     prefetch: int = 2
     # pipelined planning (docs/PERFORMANCE.md; validated by CFG310):
     # plan_ahead=N keeps N steps planned beyond the newest fetch so
-    # get_batch never waits on the planner in steady state; 0 restores
-    # the fully demand-driven serial path.  fanout_rpc=False falls back
-    # to one-RPC-at-a-time planning (the measured baseline in
-    # benchmarks/orchestration.run_pipeline).
+    # get_batch never waits on the planner in steady state; 0 plans
+    # each step only when a client asks for it.
     plan_ahead: int = 2
-    fanout_rpc: bool = True
     buffer_target: int = 256         # loader read-buffer depth (records)
     auto_partition: bool = True
     limits: PartitionLimits = dataclasses.field(
@@ -61,7 +58,6 @@ class OverlordConfig:
     checkpoint_dir: Optional[str] = None
     planner_ckpt_every: int = 1
     loader_ckpt_every: int = 8
-    restore_delay_s: float = 0.0     # simulated persistent-store latency
     # durable job recovery (docs/FAULT_TOLERANCE.md; validated by CFG311):
     # with checkpoint_dir set, every manifest_every-th step_done commits a
     # crash-consistent epoch manifest (blobs + delivery ledger) that
@@ -119,7 +115,6 @@ class Overlord:
         self.store = CheckpointStore(cfg.checkpoint_dir,
                                      cfg.planner_ckpt_every,
                                      cfg.loader_ckpt_every,
-                                     cfg.restore_delay_s,
                                      keep_epochs=cfg.keep_epochs)
         self.dlq = DeadLetterQueue(cfg.dlq_capacity)
         self.ledger = None
@@ -203,7 +198,7 @@ class Overlord:
             budget_tokens=cfg.budget_tokens(self.tree),
             seq_len=cfg.seq_len, rows=cfg.rows_per_microbatch, seed=cfg.seed,
             ledger=self.ledger, telemetry=self.telemetry,
-            plan_ahead=cfg.plan_ahead, fanout=cfg.fanout_rpc)
+            plan_ahead=cfg.plan_ahead)
         self.planner = self.runtime.spawn(
             "planner", Planner(loaders=dict(self.loaders),
                                constructors=dict(self.constructors),

@@ -63,8 +63,7 @@ class Planner(Actor):
                  ledger=None,
                  call_retry: Optional[RetryPolicy] = None,
                  telemetry: Optional[Telemetry] = None,
-                 plan_ahead: int = 0,
-                 fanout: bool = True):
+                 plan_ahead: int = 0):
         self.tree = tree
         self.schedule = schedule
         self.strategy = strategy
@@ -98,9 +97,8 @@ class Planner(Actor):
         # pipelined planning (docs/PERFORMANCE.md): the Overlord casts
         # advance_to(step + plan_ahead) after every fetch, so steps ahead
         # of the consumer are planned on this mailbox thread while the
-        # trainer consumes; fanout=False restores the serial-RPC baseline
+        # trainer consumes
         self.plan_ahead = max(int(plan_ahead), 0)
-        self.fanout = fanout
         self._last_requested = -1
 
     # -- wiring ------------------------------------------------------------
@@ -154,18 +152,10 @@ class Planner(Actor):
         the collect stage pays one max-latency, not a sum of round-trips.
         """
         alive = {n: h for n, h in self.loaders.items() if h.alive}
-        if self.fanout:
-            fo = FanOut(telemetry=self.telemetry)
-            for name, h in alive.items():
-                fo.submit(name, h, "snapshot", timeout=10)
-            snaps = fo.gather()
-        else:
-            snaps = {}
-            for name, h in alive.items():   # perf: serial ok — baseline
-                try:
-                    snaps[name] = h.call("snapshot", timeout=10)
-                except Exception:
-                    continue
+        fo = FanOut(telemetry=self.telemetry)
+        for name, h in alive.items():
+            fo.submit(name, h, "snapshot", timeout=10)
+        snaps = fo.gather()
         meta, owner, degraded = [], {}, set()
         for name in alive:                 # loader order, not completion
             snap = snaps.get(name)
@@ -314,27 +304,17 @@ class Planner(Actor):
         return plan
 
     def _prepare_wave(self, by_loader: dict) -> dict:
-        """prepare() on every owning loader; fan-out unless in serial
-        baseline mode.  Returns loader name -> samples (absent on
-        failure — supervision handles the dead loader)."""
+        """prepare() on every owning loader as one fan-out wave.  Returns
+        loader name -> samples (absent on failure — supervision handles
+        the dead loader)."""
         targets = {ln: (self.loaders.get(ln),
                         [e.sample_id for e in entries])
                    for ln, entries in by_loader.items()}
-        if self.fanout:
-            fo = FanOut(telemetry=self.telemetry)
-            for ln, (h, ids) in targets.items():
-                if h is not None and h.alive:
-                    fo.submit(ln, h, "prepare", ids, timeout=60)
-            return fo.gather()
-        prepared = {}
-        for ln, (h, ids) in targets.items():   # perf: serial ok — baseline
-            if h is None or not h.alive:
-                continue
-            try:
-                prepared[ln] = h.call("prepare", ids, timeout=60)
-            except Exception:
-                continue
-        return prepared
+        fo = FanOut(telemetry=self.telemetry)
+        for ln, (h, ids) in targets.items():
+            if h is not None and h.alive:
+                fo.submit(ln, h, "prepare", ids, timeout=60)
+        return fo.gather()
 
     def _ingest_wave(self, step: int, plan: LoadingPlan,
                      deposits: dict) -> dict:
@@ -349,21 +329,11 @@ class Planner(Actor):
                 per_src[src][0].append(s)
                 per_src[src][1].append(b)
             payloads[bucket] = dict(per_src)
-        if self.fanout:
-            fo = FanOut(telemetry=self.telemetry)
-            for bucket, h in self.constructors.items():
-                fo.submit(bucket, h, "ingest", step, payloads[bucket],
-                          plan.bins, timeout=30, retry=self.call_retry)
-            return fo.gather()
-        accepted = {}
-        for bucket, h in self.constructors.items():  # perf: serial ok
-            try:
-                accepted[bucket] = h.call(
-                    "ingest", step, payloads[bucket], plan.bins,
-                    timeout=30, retry=self.call_retry)
-            except Exception:
-                continue   # constructor unreachable: skip its share
-        return accepted
+        fo = FanOut(telemetry=self.telemetry)
+        for bucket, h in self.constructors.items():
+            fo.submit(bucket, h, "ingest", step, payloads[bucket],
+                      plan.bins, timeout=30, retry=self.call_retry)
+        return fo.gather()
 
     def _record_plan_metrics(self, plan: LoadingPlan):
         """Balance/throughput gauges derived from the emitted plan."""
@@ -489,18 +459,10 @@ class Planner(Actor):
         handles = {n: h for n, h in self.loaders.items() if h.alive}
         handles.update({f"constructor:{b}": h
                         for b, h in self.constructors.items() if h.alive})
-        if self.fanout:
-            fo = FanOut(telemetry=self.telemetry)
-            for name, h in handles.items():
-                fo.submit(name, h, "checkpoint_state", timeout=30)
-            cut["actors"] = fo.gather()
-        else:
-            for name, h in handles.items():  # perf: serial ok — baseline
-                try:
-                    cut["actors"][name] = h.call("checkpoint_state",
-                                                 timeout=30)
-                except Exception:
-                    continue
+        fo = FanOut(telemetry=self.telemetry)
+        for name, h in handles.items():
+            fo.submit(name, h, "checkpoint_state", timeout=30)
+        cut["actors"] = fo.gather()
         return cut
 
     def rollback_to(self, step: int):

@@ -3,8 +3,8 @@
 The paper's multisource memory argument (§2.3) rests on per-open-file state:
 socket, footer/schema metadata, row-group index, read buffer.  We reproduce
 that faithfully: every ``SourceReader`` holds real bytes for each of those
-and reports them, so the memory benchmarks (Figs. 4/5/14/15) measure actual
-resident state rather than assumed constants.
+and reports them, so the plane's memory report measures actual resident
+state rather than assumed constants.
 
 File layout:  [row_group_0][row_group_1]...[footer][footer_len(8B)][MAGIC]
 Row groups are pickled column dicts; the footer carries schema, per-group

@@ -5,8 +5,8 @@ token ids (text) and patch-embedding token counts (image/video/audio).
 Real work is done (deterministic tokenization from the record seed) so the
 pipeline is end-to-end runnable; in addition every transform reports its
 *virtual cost* in normalized cpu-units — the quantity the AutoScaler
-provisions for (Fig. 5's heterogeneity), so benchmarks on this 1-core
-container can reason about fleet sizing without wall-clock noise.
+provisions for (Fig. 5's heterogeneity), so fleet sizing can be reasoned
+about without wall-clock noise.
 """
 from __future__ import annotations
 

@@ -1,33 +1,14 @@
-"""Production mesh construction.
+"""Mesh construction.
 
-Defined as FUNCTIONS (never module-level constants) so importing this
-module never touches jax device state — required because the dry-run pins
-``xla_force_host_platform_device_count`` before first jax init.
+A FUNCTION (never a module-level constant) so importing this module never
+touches jax device state.
 """
 from __future__ import annotations
 
 import jax
 
 
-def make_production_mesh(*, multi_pod: bool = False):
-    """16x16 single pod (256 chips) or 2x16x16 multi-pod (512 chips)."""
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
 def make_local_mesh():
     """1-device mesh with the production axis names (CPU tests)."""
     n = jax.device_count()
     return jax.make_mesh((1, n), ("data", "model"))
-
-
-def data_axes(mesh) -> tuple[str, ...]:
-    return tuple(a for a in ("pod", "data") if a in mesh.axis_names)
-
-
-def dp_degree(mesh) -> int:
-    d = 1
-    for a in data_axes(mesh):
-        d *= mesh.shape[a]
-    return d

@@ -1,7 +1,8 @@
-"""Unified model API over all families + ShapeDtypeStruct input specs.
+"""Unified model API over all families.
 
-``Model`` bundles the per-family functions behind one interface used by the
-trainer, the serving path, and the multi-pod dry-run.
+``Model`` bundles the per-family functions behind one interface.  The
+trainer runs ``forward``; ``prefill``, ``decode_step``, ``init_cache`` and
+``cache_axes`` are reached only from tests and ``chip_smoke.py``.
 """
 from __future__ import annotations
 
@@ -11,7 +12,7 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.configs.base import ModelConfig, ShapeConfig
+from repro.configs.base import ModelConfig
 from repro.models import encdec, hybrid, rwkv_model, transformer
 from repro.models import params as pdefs
 
@@ -79,56 +80,3 @@ def build_model(cfg: ModelConfig) -> Model:
             cfg, b, m, dtype),
         cache_axes=lambda: mod.cache_logical_axes(cfg),
     )
-
-
-# ----------------------------------------------------------- input specs
-def _sds(shape, dtype):
-    return jax.ShapeDtypeStruct(shape, dtype)
-
-
-def input_specs(cfg: ModelConfig, shape: ShapeConfig) -> dict:
-    """ShapeDtypeStruct stand-ins for every model input of this cell.
-
-    train/prefill: a packed token batch (+ modality stubs).
-    decode: one new token; the KV cache spec is built separately via
-    ``Model.init_cache`` + ``jax.eval_shape``.
-    """
-    b, s = shape.global_batch, shape.seq_len
-    i32 = jnp.int32
-    if shape.kind == "decode":
-        return {"tokens": _sds((b, 1), i32)}
-
-    batch = {
-        "tokens": _sds((b, s), i32),
-        "segment_ids": _sds((b, s), i32),
-        "positions": _sds((b, s), i32),
-    }
-    if shape.kind == "train":
-        batch["labels"] = _sds((b, s), i32)
-    if cfg.family == "vlm" and cfg.image_token_frac > 0:
-        n_img = int(s * cfg.image_token_frac)
-        batch["image_embeds"] = _sds((b, n_img, cfg.d_model), jnp.bfloat16)
-        batch["image_positions"] = _sds((b, n_img), i32)
-    if cfg.family == "audio":
-        batch["enc_embeds"] = _sds((b, cfg.encoder_frames, cfg.d_model),
-                                   jnp.bfloat16)
-    return batch
-
-
-def batch_logical_axes(cfg: ModelConfig, shape: ShapeConfig) -> dict:
-    """Logical sharding axes mirroring ``input_specs``."""
-    if shape.kind == "decode":
-        return {"tokens": ("batch", None)}
-    axes = {
-        "tokens": ("batch", "seq"),
-        "segment_ids": ("batch", "seq"),
-        "positions": ("batch", "seq"),
-    }
-    if shape.kind == "train":
-        axes["labels"] = ("batch", "seq")
-    if cfg.family == "vlm" and cfg.image_token_frac > 0:
-        axes["image_embeds"] = ("batch", "seq", "act_embed")
-        axes["image_positions"] = ("batch", "seq")
-    if cfg.family == "audio":
-        axes["enc_embeds"] = ("batch", "seq", "act_embed")
-    return axes

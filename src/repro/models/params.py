@@ -5,7 +5,7 @@ initializer).  From one definition tree we derive:
 
   * materialized parameters  (``init_params``)
   * logical PartitionSpecs    (``logical_specs``)  -> sharding/logical.py
-  * abstract shapes           (``abstract_params``) for the dry-run
+  * abstract shapes           (``abstract_params``) for compile-only checks
 
 Keeping a single source of truth prevents params/spec drift, which is the
 usual failure mode of hand-written sharding tables.
@@ -91,7 +91,7 @@ def init_params(defs, key: jax.Array, dtype=jnp.bfloat16):
 
 
 def abstract_params(defs, dtype=jnp.bfloat16):
-    """ShapeDtypeStruct tree (dry-run: no allocation)."""
+    """ShapeDtypeStruct tree (no allocation)."""
     return jax.tree.map(
         lambda d: jax.ShapeDtypeStruct(d.shape, d.dtype or dtype),
         defs, is_leaf=is_def)

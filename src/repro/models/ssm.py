@@ -11,8 +11,8 @@ with a_t = exp(dt_t * A), A = -exp(A_log) < 0, dt_t = softplus(...).
 
 Simplifications vs the reference CUDA implementation (documented in
 DESIGN.md): single B/C group; the causal depthwise conv is applied to the
-SSM input stream only.  Both preserve the compute/memory character that the
-dry-run and roofline analysis measure.
+SSM input stream only.  Both preserve the block's compute/memory
+character.
 
 Packing semantics: the SSM state resets exactly at segment starts (tracked
 as reset COUNTS, see the chunked scan); the depthwise conv window leaks up

@@ -2,7 +2,6 @@ from repro.sharding.logical import (  # noqa: F401
     ShardingRules,
     TRAIN_RULES,
     DECODE_RULES,
-    LONG_DECODE_RULES,
     current_rules,
     shard,
     spec_for,

@@ -70,14 +70,6 @@ DECODE_RULES.update({
     "act_heads": (),
 })
 
-# Long-context decode (batch=1): nothing to shard on the batch axis, so the
-# KV/state sequence dim takes both data and tensor axes.
-LONG_DECODE_RULES: AxisMap = dict(_COMMON)
-LONG_DECODE_RULES.update({
-    "kv_seq": ("data", "model"),
-    "act_heads": (),
-})
-
 
 @dataclasses.dataclass
 class ShardingRules:

@@ -1,4 +1,4 @@
-"""Loss + train/serve step factories (pjit-ready, shape-polymorphic)."""
+"""Loss + train step factories (pjit-ready, shape-polymorphic)."""
 from __future__ import annotations
 
 import dataclasses
@@ -98,16 +98,3 @@ def make_train_step(model: Model, opt_cfg: AdamWConfig = AdamWConfig()):
         return TrainState(new_params, new_opt), metrics
 
     return train_step
-
-
-def make_prefill_step(model: Model):
-    def prefill_step(params, batch):
-        return model.prefill(_cast_for_compute(params), batch)
-    return prefill_step
-
-
-def make_decode_step(model: Model):
-    def decode_step(params, cache, tokens, pos):
-        return model.decode_step(_cast_for_compute(params), cache, tokens,
-                                 pos)
-    return decode_step

@@ -1,7 +1,7 @@
 import os
 import sys
 
-# smoke tests and benches must see 1 device (the dry-run sets 512 itself)
+# the tests run on the host CPU, never on an accelerator
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import numpy as np

@@ -136,12 +136,18 @@ if given is not None:
 
     @given(costs_strategy, st.integers(1, 8))
     @settings(max_examples=50, deadline=None)
-    def test_greedy_within_4_3_of_lower_bound(costs, n_bins):
-        """LPT is a 4/3-approx: max load <= 4/3 * OPT + max item slack."""
+    def test_greedy_within_graham_list_bound(costs, n_bins):
+        """Graham's list-scheduling bound: the last item placed on the
+        heaviest bin started on the lightest one, so the max load is at
+        most mean + (1 - 1/m) * max item, for any order and hence LPT.
+
+        4/3 x max(mean, max item) is not a bound: 4/3 bounds LPT against
+        OPT, and max(mean, max item) can sit below OPT.  Five unit items
+        in four bins give LPT = OPT = 2 > 4/3 x 1.25."""
         assign = greedy_binpack(costs, n_bins)
         got = max(bin_loads(costs, assign, n_bins))
-        lower = max(sum(costs) / n_bins, max(costs) if costs else 0.0)
-        assert got <= 4.0 / 3.0 * lower + 1e-6
+        bound = sum(costs) / n_bins + (1 - 1 / n_bins) * max(costs)
+        assert got <= bound + 1e-6
 
     @given(st.lists(st.floats(min_value=0.1, max_value=1e4), min_size=2,
                     max_size=40), st.integers(2, 6))

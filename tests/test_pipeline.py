@@ -58,7 +58,7 @@ def test_plan_ahead_runs_ahead_of_consumer(source_paths):
     """With plan_ahead=2 the planner frontier stays beyond the newest
     consumed step, the prefetch-depth gauge is exported, and steps are
     fully planned BEFORE the trainer asks for them."""
-    ov = mk(source_paths, plan_ahead=2, fanout_rpc=True, shadows=False)
+    ov = mk(source_paths, plan_ahead=2, shadows=False)
     try:
         last = 7
         run_steps(ov, 0, last + 1)
@@ -90,11 +90,11 @@ def test_plan_ahead_runs_ahead_of_consumer(source_paths):
         ov.shutdown()
 
 
-def test_serial_baseline_still_works(source_paths):
-    """plan_ahead=0 + fanout_rpc=False is the measured pre-pipeline
-    baseline (benchmarks/orchestration.run_pipeline) — it must keep
-    delivering, just demand-driven."""
-    ov = mk(source_paths, plan_ahead=0, fanout_rpc=False, shadows=False)
+def test_demand_driven_path_delivers(source_paths):
+    """plan_ahead=0 plans each step only when a client asks for it.  That
+    is the path every step takes on a cold start and after a replan, so
+    it must keep delivering on its own."""
+    ov = mk(source_paths, plan_ahead=0, shadows=False)
     try:
         run_steps(ov, 0, 3, compute_s=0.0)
         assert ov.planner.call("planned_through", timeout=10) >= 2
@@ -119,7 +119,7 @@ def test_replay_after_recovery_keeps_ledger_clean(source_paths):
     """Planner crash mid-pipeline: prefetched-but-lost plans are replanned,
     already-assembled steps win, and the delivery ledger still proves
     no-loss / no-duplication across the recovery."""
-    ov = mk(source_paths, plan_ahead=2, fanout_rpc=True, shadows=False,
+    ov = mk(source_paths, plan_ahead=2, shadows=False,
             ledger=True, planner_ckpt_every=1)
     try:
         run_steps(ov, 0, 4)
